@@ -10,7 +10,15 @@ from typing import Dict, List, Optional, Tuple
 from repro.core import Counters, join_stream, make_joiner
 from repro.data.synth import StreamSpec, synthetic_stream
 
-__all__ = ["BENCH_SPECS", "run_config", "Row", "grid", "fmt_rows"]
+__all__ = [
+    "BENCH_SPECS",
+    "DEVICE_PEAKS",
+    "device_peaks",
+    "run_config",
+    "Row",
+    "grid",
+    "fmt_rows",
+]
 
 # Scaled-down analogues of the paper's Table 1 (sizes cut so the full
 # harness completes in minutes on one CPU core; density + timestamp
@@ -21,6 +29,26 @@ BENCH_SPECS: Dict[str, StreamSpec] = {
     "blogs": StreamSpec("blogs", 4000, 4096, 24.0, "bursty", rate=1.0),
     "tweets": StreamSpec("tweets", 6000, 8192, 8.0, "bursty", rate=1.0),
 }
+
+
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# "TPU v5 lite" (v5e): Google Cloud documentation, "TPU v5e" — 197 TFLOP/s
+# bf16, HBM at 819 GB/s.
+DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+}
+
+
+def device_peaks(device) -> Dict[str, float]:
+    """The peaks of ``device``; a kind with no published entry is an error,
+    never a default."""
+    try:
+        return DEVICE_PEAKS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device.device_kind!r}; "
+            f"add them to benchmarks/common.py DEVICE_PEAKS with a source"
+        ) from None
 
 
 @dataclasses.dataclass

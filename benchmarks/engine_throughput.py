@@ -54,6 +54,7 @@ from repro.kernels.sssj_join import (
     sssj_join_scores,
     tile_candidates,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import publish_counters
 
 from .common import Row, run_config
@@ -158,7 +159,7 @@ def run(fast: bool = True, smoke: bool = False) -> List[Row]:
     def cfg(capacity, **kw):
         base = dict(theta=theta, lam=lam, capacity=capacity, d=d,
                     micro_batch=mb, max_pairs=max_pairs, tile_k=tile_k,
-                    block_q=mb, block_w=mb, chunk_d=min(d, 128))
+                    block_q=mb, block_w=mb)
         base.update(kw)
         return EngineConfig(**base)
 
@@ -337,6 +338,7 @@ def check(rows: List[Row]) -> List[str]:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes (CI): exercises every path, relaxes "

@@ -17,7 +17,7 @@ import numpy as np
 from repro.kernels.sssj_join import compact_pairs, sssj_join_tiles
 from repro.kernels.sssj_join.ops import sssj_join_scores
 
-from .common import Row
+from .common import Row, device_peaks
 
 
 def run(fast: bool = True) -> List[Row]:
@@ -70,12 +70,22 @@ def run(fast: bool = True) -> List[Row]:
             100.0 * (dt_c - dt) / dt,
             f"{dt_c*1e3:.1f} ms/join+compact, {int(buf.n_pairs)} pairs",
         ))
-        # static work model of the Pallas kernel on v5e for this shape:
-        # full-tile FLOPs / peak — the interpret-mode runs validate
-        # correctness (tests), the TPU projection belongs to EXPERIMENTS.md
-        v5e = 197e12
-        t_roof = 2 * Q * W * d / v5e
-        rows.append(Row(f"kernel/v5e_roofline/Q{Q}xW{W}xd{d}/us", t_roof * 1e6))
+        # static work model of the Pallas kernel for this shape on the chip
+        # this runs on: the larger of full-tile FLOPs over the f32 matmul
+        # rate (HIGHEST precision counted as 6 bf16 MXU passes) and the
+        # query + window bytes over HBM bandwidth.  A CPU has no entry —
+        # off the chip the row is not produced.
+        dev = jax.devices()[0]
+        if dev.platform == "tpu":
+            peaks = device_peaks(dev)
+            t_mxu = 2 * Q * W * d / (peaks["bf16_flops"] / 6)
+            t_hbm = 4 * (Q + W) * d / peaks["hbm_bytes_per_s"]
+            rows.append(Row(
+                f"kernel/roofline/Q{Q}xW{W}xd{d}/us",
+                max(t_mxu, t_hbm) * 1e6,
+                f"{dev.device_kind}, "
+                f"{'compute' if t_mxu >= t_hbm else 'memory'}-bound",
+            ))
     return rows
 
 

@@ -14,6 +14,8 @@ import argparse
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import (
     engine_throughput, fig2_entries_ratio, fig34_mb_vs_str, fig56_indexes,
     fig789_params, kernel_bench, roofline_table, table2_completion,
@@ -34,6 +36,7 @@ MODULES = [
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="larger scales (slower, closer to the paper's)")

@@ -59,15 +59,18 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 import time
 from typing import List
 
-# host-platform device-count trick: must land in the environment BEFORE
-# jax initializes (which the repro imports below trigger), so sniff argv
-# here rather than waiting for argparse (both --shards N and --shards=N;
-# malformed values are left for argparse to reject properly)
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import require_devices, use_host_devices
+
+# a CPU run shards over virtual host devices: the flag must land in the
+# environment BEFORE jax initializes (which the repro imports below
+# trigger), so sniff argv here rather than waiting for argparse (both
+# --shards N and --shards=N; malformed values are left for argparse to
+# reject properly).  A run on a chip uses its real devices.
 def _sniff_shards(argv) -> int:
     for i, a in enumerate(argv):
         v = None
@@ -84,13 +87,8 @@ def _sniff_shards(argv) -> int:
 
 
 _n = _sniff_shards(sys.argv)
-if _n > 1 and "xla_force_host_platform_device_count" not in os.environ.get(
-    "XLA_FLAGS", ""
-):
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={_n}"
-    ).strip()
+if _n > 1:
+    use_host_devices(_n)
 
 import numpy as np
 
@@ -173,7 +171,7 @@ def run(
     cfg = EngineConfig(
         theta=theta, lam=lam, capacity=cap, d=d, micro_batch=mb,
         max_pairs=4096, tile_k=mb * mb, block_q=mb, block_w=mb,
-        chunk_d=min(d, 128), eviction=eviction, quotas=quotas,
+        eviction=eviction, quotas=quotas,
     )
     n_items = n_tenants * rounds * per_round
     events = _traffic(n_tenants, rounds, per_round, d)
@@ -216,17 +214,12 @@ def run(
         # per-tenant pair sets are a hard claim, throughput is informative
         import jax
 
-        if jax.device_count() < shards:
-            raise RuntimeError(
-                f"--shards {shards} needs ≥{shards} devices; found "
-                f"{jax.device_count()} (XLA_FLAGS device-count trick "
-                f"not applied?)"
-            )
+        require_devices(shards, f"--shards {shards}")
         mesh = jax.make_mesh((shards,), ("data",))
         scfg = EngineConfig(
             theta=theta, lam=lam, capacity=cap // shards, d=d,
             micro_batch=mb, max_pairs=4096, tile_k=mb * mb, block_q=mb,
-            block_w=mb, chunk_d=min(d, 128), eviction=eviction,
+            block_w=mb, eviction=eviction,
             quotas=None if quotas is None
             else quota_partition(cap // shards, [1.0] * n_tenants),
         )
@@ -285,6 +278,7 @@ def run_bursty(smoke: bool = False, shards: int = 1) -> List[Row]:
     if shards > 1:
         import jax
 
+        require_devices(shards, f"--shards {shards}")
         engine = ShardedFacade(jax.make_mesh((shards,), ("data",)))
     rows.append(Row("bursty/smoke_mode", float(smoke)))
     rows.append(Row("bursty/shards", float(shards)))
@@ -300,7 +294,7 @@ def run_bursty(smoke: bool = False, shards: int = 1) -> List[Row]:
         cfg = EngineConfig(
             theta=th_slow, lam=lam_slow, capacity=cap // shards, d=d,
             micro_batch=mb, max_pairs=8192, tile_k=mb * mb, block_q=mb,
-            block_w=mb, chunk_d=min(d, 128), join_impl="scan",
+            block_w=mb, join_impl="scan",
             eviction=eviction, quotas=quotas,
         )
         rt = MultiTenantRuntime(cfg, table, span=2,
@@ -399,7 +393,6 @@ def run_latency(smoke: bool = False):
     cfg = EngineConfig(
         theta=theta, lam=lam, capacity=cap, d=d, micro_batch=mb,
         max_pairs=8192, tile_k=mb * mb, block_q=mb, block_w=mb,
-        chunk_d=min(d, 128),
     )
     rt = MultiTenantRuntime(cfg, table, span=2, max_queue_per_tenant=1 << 20)
     # warmup: one dispatch + drain compiles the (fixed-shape) step; the
@@ -543,6 +536,7 @@ def check(rows: List[Row]) -> List[str]:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes (CI): exercises both drivers, relaxes "

@@ -33,6 +33,7 @@ from typing import List
 
 from repro.data.synth import topic_drift_stream
 from repro.engine import EngineConfig, StreamEngine
+from repro.launch.compile_cache import enable_compile_cache
 
 from .common import Row
 
@@ -77,7 +78,7 @@ def run(fast: bool = True, smoke: bool = False) -> List[Row]:
     def cfg(capacity, theta, lam, gate=None):
         return EngineConfig(
             theta=theta, lam=lam, capacity=capacity, d=d, micro_batch=mb,
-            block_q=mb, block_w=mb, chunk_d=min(d, 128), tile_k=256,
+            block_q=mb, block_w=mb, tile_k=256,
             max_pairs=4096, join_impl="scan", l2_gate=gate,
         )
 
@@ -168,6 +169,7 @@ def check(rows: List[Row]) -> List[str]:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes (CI): exercises every path, relaxes "

@@ -9,8 +9,8 @@ groups near-duplicates under namespaced (tenant, uid) keys.
 
 The same traffic then replays on the **sharded** variant (DESIGN.md §10):
 the identical service facade over ``ShardedFacade`` spreads the ring
-window across P in-process shards (host-platform device-count trick) and
-must produce the identical per-tenant groups.
+window across P shards (real devices on a chip, virtual host devices on a
+CPU) and must produce the identical per-tenant groups.
 
 The final act is the **bursty-tenant demo** (DESIGN.md §11): one tenant
 floods a deliberately undersized window at ~15× the others' rate.  Under
@@ -23,15 +23,13 @@ intact.
     PYTHONPATH=src python examples/multi_tenant_service.py
 """
 
-import os
+from repro.launch.mesh import require_devices, use_host_devices
 
 N_SHARDS = 2
-# the device-count trick must land before jax initializes (first repro import)
-if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={N_SHARDS}"
-    ).strip()
+# a CPU run shards over virtual host devices; the flag must land before
+# jax initializes its backends (the first repro import below that touches
+# devices) — a run on a chip uses its real devices
+use_host_devices(N_SHARDS)
 
 import numpy as np  # noqa: E402
 
@@ -88,6 +86,7 @@ print(f"✓ {K} tenants, {stats['n_items']} documents on one engine; "
 # ---- sharded variant: same service, ring window over N_SHARDS shards ---- #
 import jax  # noqa: E402
 
+require_devices(N_SHARDS, "the sharded variant")
 mesh = jax.make_mesh((N_SHARDS,), ("data",))
 svc_sh = drive(MultiTenantSSSJService(
     table, dim=DIM, capacity=1024, micro_batch=32, mesh=mesh,

@@ -1,6 +1,7 @@
 from .sharding import (  # noqa: F401
     AxisRules,
     DEFAULT_RULES,
+    auto_axes,
     axis_ctx,
     constrain,
     resolve_pspec,

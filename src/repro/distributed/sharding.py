@@ -20,28 +20,15 @@ import threading
 from typing import Dict, Optional, Sequence, Tuple, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax ≥ 0.6 exposes shard_map at the top level
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # jax 0.4.x: experimental module, check_rep spelling
-    import functools
-
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_exp(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
-
-    functools.update_wrapper(shard_map, _shard_map_exp)
+from jax import shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
     "AxisRules",
     "DEFAULT_RULES",
     "axis_ctx",
     "use_rules",
+    "auto_axes",
     "constrain",
     "resolve_pspec",
     "param_shardings",
@@ -96,6 +83,19 @@ DEFAULT_RULES = AxisRules(
     # are recorded in EXPERIMENTS.md §Dry-run.
     allow_uneven=(),
 )
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """The same devices and axis names with every axis ``Auto``.
+
+    ``jax.make_mesh`` builds ``Explicit`` axes by default; code written
+    against GSPMD propagation (``shard_map`` plus ordinary jnp on its
+    gathered outputs) keeps its own view of the caller's mesh through this,
+    so an explicit-axis mesh and an auto-axis mesh behave the same."""
+    return Mesh(
+        mesh.devices, mesh.axis_names,
+        axis_types=(AxisType.Auto,) * len(mesh.axis_names),
+    )
 
 
 class _Ctx(threading.local):

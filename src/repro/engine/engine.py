@@ -116,6 +116,10 @@ class EngineConfig:
             if (isinstance(v, bool) or not isinstance(v, (int, np.integer))
                     or v < 1):
                 raise ValueError(f"{name} must be a positive int, got {v!r}")
+        # a feature width below one chunk is one chunk: chunk_d ≤ d always
+        # holds, so the window's strip summary and every join agree on the
+        # chunking and no join routes around the kernel for a narrow d
+        object.__setattr__(self, "chunk_d", min(self.chunk_d, self.d))
         if self.shard_k is not None and self.shard_k < 1:
             raise ValueError(f"shard_k must be ≥ 1, got {self.shard_k}")
         if self.micro_batch > self.capacity:

@@ -58,7 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..distributed.sharding import AxisRules, DEFAULT_RULES, shard_map
+from ..distributed.sharding import AxisRules, DEFAULT_RULES, auto_axes, shard_map
 from ..kernels.sssj_join import PairBuffer, PairCandidates, merge_candidates
 from ..kernels.sssj_join.gate import StripSummary, init_strip_summary
 from ..obs import merge_disjoint, publish_flat
@@ -107,19 +107,13 @@ def init_sharded_window(
     ``(n_shards, n_lanes)`` replicated-in-lane arrays: each shard owns
     its row — quota sub-rings (and their cursors) are **shard-local**.
     """
+    mesh = auto_axes(mesh)
     n = mesh.shape[axis]
     if n_lanes is None:
         n_lanes = cfg.n_lanes
-    state = init_window(cfg.capacity * n, cfg.d)
-    shard = NamedSharding(mesh, P(axis))
-    lane_shard = NamedSharding(mesh, P(axis, None))
 
     def lanes():
-        # distinct buffers — the step donates the whole pytree
-        return (
-            None if n_lanes is None
-            else jax.device_put(jnp.zeros((n, n_lanes), jnp.int32), lane_shard)
-        )
+        return None if n_lanes is None else jnp.zeros((n, n_lanes), jnp.int32)
 
     def summary():
         if not cfg.gate_enabled:
@@ -133,24 +127,29 @@ def init_sharded_window(
             cfg.capacity, cfg.d, block_w=cfg.block_w, chunk_d=cfg.chunk_d
         )
         return jax.tree.map(
-            lambda x: jax.device_put(
-                jnp.tile(x, (n,) + (1,) * (x.ndim - 1)),
-                lane_shard if x.ndim > 1 else shard,
-            ),
-            s1,
+            lambda x: jnp.tile(x, (n,) + (1,) * (x.ndim - 1)), s1
         )
 
-    return WindowState(
-        vecs=jax.device_put(state.vecs, NamedSharding(mesh, P(axis, None))),
-        ts=jax.device_put(state.ts, shard),
-        uids=jax.device_put(state.uids, shard),
-        cursor=jax.device_put(jnp.zeros((n,), jnp.int32), shard),
-        overflow=jax.device_put(jnp.zeros((n,), jnp.int32), shard),
-        sids=jax.device_put(state.sids, shard),
-        lane_cursor=lanes() if cfg.eviction == "quota" else None,
-        lane_overflow=lanes(),
-        summary=summary(),
-    )
+    def build() -> WindowState:
+        state = init_window(cfg.capacity * n, cfg.d)
+        return state._replace(
+            cursor=jnp.zeros((n,), jnp.int32),
+            overflow=jnp.zeros((n,), jnp.int32),
+            lane_cursor=lanes() if cfg.eviction == "quota" else None,
+            lane_overflow=lanes(),
+            summary=summary(),
+        )
+
+    # every leaf is sharded along its leading axis, and the state is built
+    # in place by one jitted program: no device ever holds the global
+    # window (at 2^20 rows × 768 per shard that is 3 GiB per device), and
+    # every leaf is its own buffer, as the donating step requires
+    def leading(x):
+        return NamedSharding(mesh, P(axis, *([None] * (x.ndim - 1))))
+
+    return jax.jit(
+        build, out_shardings=jax.tree.map(leading, jax.eval_shape(build))
+    )()
 
 
 def make_sharded_batch_step(cfg: EngineConfig, mesh: Mesh, axis: str, table=None):
@@ -167,8 +166,13 @@ def make_sharded_batch_step(cfg: EngineConfig, mesh: Mesh, axis: str, table=None
     shard, and per-query-row ``(theta_q, lam_q)`` are looked up inside the
     ``shard_map`` from the table's device arrays (broadcast replicated
     through the in_specs).
-    """
 
+    The step runs on the :func:`~repro.distributed.sharding.auto_axes`
+    view of ``mesh``: the post-gather merge is ordinary jnp on replicated
+    buffers, which an ``Explicit``-axis mesh (the ``jax.make_mesh``
+    default) would refuse to gather from a sharded operand.
+    """
+    mesh = auto_axes(mesh)
     if cfg.emit_dense:
         raise ValueError(
             "emit_dense is the single-device test oracle; the sharded engine "

@@ -8,12 +8,14 @@ from .compact import (  # noqa: F401
     tile_emit_counts,
 )
 from .gate import (  # noqa: F401
+    GATE_KERNEL,
     StripSummary,
     init_strip_summary,
     refresh_strip_summary,
     strip_gate,
     summarize_strips,
 )
+from .kernel import CANDIDATE_KERNEL, tpu_kernels  # noqa: F401
 from .ops import (  # noqa: F401
     JoinCandidates,
     NEG_UID,

@@ -51,13 +51,14 @@ gated off by both the uid check and the time bound.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 __all__ = [
+    "GATE_KERNEL",
     "StripSummary",
     "init_strip_summary",
     "refresh_strip_summary",
@@ -66,6 +67,7 @@ __all__ = [
 ]
 
 _EMPTY_TS = jnp.float32(3.0e30)
+GATE_KERNEL = "sssj_strip_gate"   # pallas_call name (stable, for HLO/traces)
 
 
 class StripSummary(NamedTuple):
@@ -195,39 +197,58 @@ def refresh_strip_summary(
 # --------------------------------------------------------------------- #
 # the pre-launch gate
 # --------------------------------------------------------------------- #
+_STRIP_BLOCK_BYTES = 2 << 20   # VMEM for one vmax block of the gate grid
+
+
 def _gate_ub_kernel(qa_ref, qcn_ref, vmax_ref, cnorm_ref, ub_ref):
-    """One query tile vs every strip: ``ub[j] = max_i min(pb, lb)[i, j]``."""
-    f32 = jnp.float32
+    """One query tile vs one block of strips:
+    ``ub[j] = max_i min(pb, lb)[i, j]``.  HIGHEST precision keeps the
+    bound an f32 bound — a bf16-rounded one could fall below the score
+    it must cover."""
+    dims = (((1,), (1,)), ((), ()))
     pb = jax.lax.dot_general(
-        qa_ref[...], vmax_ref[...],
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=f32,
+        qa_ref[...], vmax_ref[...], dimension_numbers=dims,
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
     lb = jax.lax.dot_general(
-        qcn_ref[...], cnorm_ref[...],
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=f32,
+        qcn_ref[...], cnorm_ref[...], dimension_numbers=dims,
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
-    ub_ref[...] = jnp.max(jnp.minimum(pb, lb), axis=0, keepdims=True)
+    ub_ref[0] = jnp.max(jnp.minimum(pb, lb), axis=0, keepdims=True)
 
 
 def _tile_ub_pallas(qa, qcn, vmax, cnorm, *, block_q: int, interpret: bool):
+    """Grid ``(query tiles, strip blocks)``: each program stages one
+    ``(sb, d)`` block of the summary, so VMEM use is set by ``d`` and never
+    by the window's capacity.  ``sb`` is a multiple of 128 (or all strips,
+    when fewer); padded strips carry zero aggregates and are cut off."""
     Qp, d = qa.shape
     ns, nc = cnorm.shape
     nq = Qp // block_q
-    return pl.pallas_call(
+    sb = max(128, min(512, _STRIP_BLOCK_BYTES // (4 * d) // 128 * 128))
+    if ns <= sb:
+        sb = ns
+    nsp = -(-ns // sb) * sb
+    if nsp != ns:
+        vmax = jnp.pad(vmax, ((0, nsp - ns), (0, 0)))
+        cnorm = jnp.pad(cnorm, ((0, nsp - ns), (0, 0)))
+    ub = pl.pallas_call(
         _gate_ub_kernel,
-        grid=(nq,),
+        grid=(nq, nsp // sb),
         in_specs=[
-            pl.BlockSpec((block_q, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_q, nc), lambda i: (i, 0)),
-            pl.BlockSpec((ns, d), lambda i: (0, 0)),
-            pl.BlockSpec((ns, nc), lambda i: (0, 0)),
+            pl.BlockSpec((block_q, d), lambda i, s: (i, 0)),
+            pl.BlockSpec((block_q, nc), lambda i, s: (i, 0)),
+            pl.BlockSpec((sb, d), lambda i, s: (s, 0)),
+            pl.BlockSpec((sb, nc), lambda i, s: (s, 0)),
         ],
-        out_specs=pl.BlockSpec((1, ns), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nq, ns), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, sb), lambda i, s: (i, 0, s)),
+        out_shape=jax.ShapeDtypeStruct((nq, 1, nsp), jnp.float32),
         interpret=interpret,
+        name=GATE_KERNEL,
     )(qa, qcn, vmax, cnorm)
+    return ub[:, 0, :ns]
 
 
 def _chunk_norms(x: jax.Array, chunk_d: int) -> jax.Array:
@@ -252,7 +273,7 @@ def strip_gate(
     th_min,
     lam_min,
     impl: str = "jnp",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Admissible per-(query-tile × strip) launch gate.
 
@@ -267,6 +288,8 @@ def strip_gate(
       impl: ``"jnp"`` or ``"pallas"`` for the value-bound matmuls (the
         Pallas variant keeps the (Qp, n_strips) bound matrices in VMEM,
         worth it when the join itself runs as the Pallas kernel).
+      interpret: Pallas interpret mode; ``None`` runs the compiled kernel
+        on a TPU and the interpreter elsewhere.
 
     Returns:
       gate:  (nq, n_strips) bool — True where the tile must launch.
@@ -274,6 +297,8 @@ def strip_gate(
         strips_survived]`` (tiles_total is ``gate.size``, already counted
         by the engine's ``tiles`` telemetry).
     """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     Qp, d_pad = qp.shape
     nq = Qp // block_q
     ns, d_s = summary.vmax.shape

@@ -1,10 +1,13 @@
 """Public jit'd wrappers for the SSSJ blocked-join kernel.
 
-Handles padding to block multiples, suffix-norm precomputation (the ℓ2
-pruning bounds), backend auto-detection (interpret mode off-TPU), routing
-of sub-block inputs through the jnp reference (a `pallas_call` on a
-smaller-than-one-block problem only pays padding + launch overhead), and
-unpadding of the outputs.
+Handles padding to block multiples, the jnp definition of the ℓ2 suffix
+norms (:func:`suffix_chunk_norms`; the kernel computes its own from the
+tiles in VMEM), the kernel's lane layouts (:func:`window_rows`),
+backend auto-detection (interpret mode off-TPU), routing of sub-block
+inputs through the jnp reference (a `pallas_call` on a smaller-than-one-
+block problem only pays padding + launch overhead), and unpadding of the
+outputs.  ``chunk_d`` is capped at ``d``: a feature width below one chunk
+is one chunk, so every input of at least one block runs the kernel.
 
 Two join surfaces:
 
@@ -40,6 +43,7 @@ from .compact import PairCandidates, tile_candidates, tile_emit_counts
 from .gate import StripSummary, strip_gate
 from .kernel import (
     NEG_UID,
+    ROW_GROUP,
     sssj_join_candidates_kernel_call,
     sssj_join_kernel_call,
 )
@@ -51,6 +55,7 @@ __all__ = [
     "sssj_join_scores",
     "sssj_join_tiles",
     "suffix_chunk_norms",
+    "window_rows",
     "NEG_UID",
 ]
 
@@ -80,6 +85,14 @@ def _pad_rows(x: jax.Array, mult: int, fill=0):
     if pad == 0:
         return x
     return jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1), constant_values=fill)
+
+
+def window_rows(x: jax.Array, block_w: int, fill) -> jax.Array:
+    """A block-padded per-row window lane ``(Wp,)`` in the kernel's row
+    layout: ``(n_w_tiles, block_w)``, padded to a multiple of 8 tiles with
+    ``fill``, so a window tile reads its lane as one ``(1, block_w)`` row.
+    A ``(Wp, 1)`` column would pad every value to a 128-lane row in HBM."""
+    return _pad_rows(x.reshape(-1, block_w), ROW_GROUP, fill=fill)
 
 
 @functools.partial(
@@ -115,9 +128,9 @@ def sssj_join_tiles(
       uw: (W,) window uids; negative marks empty ring slots.
       theta, lam: SSSJ parameters.
       use_ref: route through the pure-jnp oracle instead of the kernel.
-        Inputs smaller than one block (Q < block_q, W < block_w, or
-        d < chunk_d) are auto-routed through the reference as well — the
-        kernel would spend its time on padding for them.
+        Inputs smaller than one block (Q < block_q or W < block_w) are
+        auto-routed through the reference as well — the kernel would spend
+        its time on padding for them.
 
     Returns:
       scores: (Q, W) f32 — decayed similarity where ≥ θ (masked by uid
@@ -136,13 +149,14 @@ def sssj_join_tiles(
 
     Q, d = q.shape
     W, _ = w.shape
+    chunk_d = min(chunk_d, d)
     # ref fallback for unaligned tiny inputs: anything smaller than a single
     # kernel block would be all padding, so the dense jnp oracle is cheaper
-    if Q < block_q or W < block_w or d < chunk_d:
+    if Q < block_q or W < block_w:
         use_ref = True
     if use_ref:
         scores = sssj_join_ref(q, w, tq, tw, uq, uw, theta=theta, lam=lam)
-        n_chunks = max(d // chunk_d, 1)
+        n_chunks = -(-d // chunk_d)
         iters = jnp.full(
             ((Q + block_q - 1) // block_q, (W + block_w - 1) // block_w),
             n_chunks,
@@ -160,14 +174,14 @@ def sssj_join_tiles(
     qp = _pad_rows(q, block_q)
     wp = _pad_rows(w, block_w)
     tqp = _pad_rows(tq, block_q)
-    twp = _pad_rows(tw, block_w)
     uqp = _pad_rows(uq, block_q, fill=NEG_UID)
-    uwp = _pad_rows(uw, block_w, fill=NEG_UID)
-    sqq = suffix_chunk_norms(qp, chunk_d)
-    sqw = suffix_chunk_norms(wp, chunk_d)
+    tw_rows = window_rows(_pad_rows(tw[:, 0], block_w), block_w, 0.0)
+    uw_rows = window_rows(
+        _pad_rows(uw[:, 0], block_w, fill=NEG_UID), block_w, NEG_UID
+    )
 
     scores, iters, counts = sssj_join_kernel_call(
-        qp, wp, tqp, twp, uqp, uwp, sqq, sqw,
+        qp, wp, tqp, tw_rows, uqp, uw_rows,
         theta=theta, lam=lam,
         block_q=block_q, block_w=block_w, chunk_d=chunk_d,
         interpret=interpret,
@@ -201,27 +215,6 @@ class JoinCandidates(NamedTuple):
     gate_stats: Optional[jax.Array] = None  # (3,) i32 [skipped_time,
     #                                         skipped_l2, strips_survived];
     #                                         zeros when no gate ran
-
-
-def _kernel_candidates(cand_idx, cand_score, emitted, uqp, uwp, block_q, block_w):
-    """Decode the kernel's in-tile flat indices into uid-level candidates."""
-    nq, nw, K = cand_idx.shape
-    valid = cand_idx >= 0
-    idx = jnp.maximum(cand_idx, 0)
-    ti = jax.lax.broadcasted_iota(jnp.int32, (nq, nw, K), 0)
-    tj = jax.lax.broadcasted_iota(jnp.int32, (nq, nw, K), 1)
-    qi = ti * block_q + idx // block_w
-    wi = tj * block_w + idx % block_w
-    uid_a = jnp.where(valid, uqp[qi], -1)
-    uid_b = jnp.where(valid, uwp[wi], -1)
-    t = nq * nw
-    return PairCandidates(
-        uid_a=uid_a.reshape(t, K),
-        uid_b=uid_b.reshape(t, K),
-        score=jnp.where(valid, cand_score, 0.0).reshape(t, K),
-        kept=jnp.minimum(emitted, K).reshape(t),
-        emitted=emitted.reshape(t),
-    )
 
 
 @functools.partial(
@@ -325,10 +318,10 @@ def sssj_join_candidates(
 
     Q, d = q.shape
     W, _ = w.shape
+    chunk_d = min(chunk_d, d)
     # sub-block inputs take the dense oracle (a kernel/scan launch would be
-    # all padding); d < chunk_d only matters to the kernel's d-chunking —
-    # the scan impl does not chunk d and stays on its no-dense-matrix path
-    if Q < block_q or W < block_w or (d < chunk_d and impl != "scan"):
+    # all padding)
+    if Q < block_q or W < block_w:
         impl = "dense"
 
     if impl == "dense":
@@ -343,7 +336,7 @@ def sssj_join_candidates(
         cands, row_mask = tile_candidates(
             scores, uq, uw, block_q=block_q, block_w=block_w, tile_k=tile_k
         )
-        n_chunks = max(d // chunk_d, 1)
+        n_chunks = -(-d // chunk_d)
         iters = jnp.full(
             ((Q + block_q - 1) // block_q, (W + block_w - 1) // block_w),
             n_chunks,
@@ -387,25 +380,28 @@ def sssj_join_candidates(
         )
 
     if impl == "pallas":
-        sqq = suffix_chunk_norms(qp, chunk_d)
-        sqw = suffix_chunk_norms(wp, chunk_d)
-        cand_idx, cand_score, emitted, row_hits, iters = (
+        ua, ub, score, emitted, row_hits, iters = (
             sssj_join_candidates_kernel_call(
-                qp, wp, tqp[:, None], twp[:, None],
-                uqp[:, None], uwp[:, None], sqq, sqw,
+                qp, wp, tqp[:, None], window_rows(twp, block_w, 0.0),
+                uqp[:, None], window_rows(uwp, block_w, NEG_UID),
                 theta=theta, lam=lam, block_q=block_q, block_w=block_w,
                 chunk_d=chunk_d, tile_k=tile_k, interpret=interpret,
                 sq=None if sqp is None else sqp[:, None],
-                sw=None if swp is None else swp[:, None],
+                sw=None if swp is None else window_rows(swp, block_w, NEG_UID),
                 theta_q=None if thp is None else thp[:, None],
                 lam_q=None if lmp is None else lmp[:, None],
-                gate=None if gate is None else gate.astype(jnp.int32),
+                gate=gate,
             )
         )
-        cands = _kernel_candidates(
-            cand_idx, cand_score, emitted, uqp, uwp, block_q, block_w
+        t = nq * nw
+        cands = PairCandidates(
+            uid_a=ua.reshape(t, tile_k),
+            uid_b=ub.reshape(t, tile_k),
+            score=score.reshape(t, tile_k),
+            kept=jnp.minimum(emitted, tile_k).reshape(t),
+            emitted=emitted.reshape(t),
         )
-        row_mask = jnp.any(row_hits > 0, axis=1).reshape(Qp)[:Q]
+        row_mask = (row_hits > 0).reshape(Qp)[:Q]
         return JoinCandidates(
             cands=cands, row_mask=row_mask, iters=iters,
             gate_stats=gate_stats,

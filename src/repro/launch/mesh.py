@@ -13,23 +13,48 @@ Topology (TPU v5e):
 
 from __future__ import annotations
 
-from typing import Optional
+import importlib.util
+import os
 
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-try:  # jax ≥ 0.6: meshes carry explicit/auto axis types
-    from jax.sharding import AxisType
+__all__ = [
+    "make_production_mesh",
+    "make_mesh_for",
+    "require_devices",
+    "use_host_devices",
+]
 
-    def _mesh(grid, axes) -> Mesh:
-        return Mesh(grid, axes, axis_types=(AxisType.Auto,) * len(axes))
-except ImportError:  # jax 0.4.x: every axis is implicitly "auto"
-    def _mesh(grid, axes) -> Mesh:
-        return Mesh(grid, axes)
+_HOST_COUNT = "--xla_force_host_platform_device_count"
 
-__all__ = ["make_production_mesh", "make_mesh_for"]
+
+def use_host_devices(n: int) -> None:
+    """Give a CPU run ``n`` virtual host devices, so a sharded path can run
+    without a multi-chip host.  A run that will land on a TPU (JAX_PLATFORMS
+    does not start with ``cpu`` and the TPU library is installed) keeps
+    its real devices.  Call before anything initializes JAX's backends —
+    importing this module does not."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    on_cpu = platforms.split(",")[0] == "cpu" or (
+        not platforms and importlib.util.find_spec("libtpu") is None
+    )
+    flags = os.environ.get("XLA_FLAGS", "")
+    if on_cpu and _HOST_COUNT not in flags:
+        os.environ["XLA_FLAGS"] = f"{flags} {_HOST_COUNT}={n}".strip()
+
+
+def require_devices(n: int, what: str) -> list:
+    """The first ``n`` devices, or a clear error naming what needed them."""
+    devs = jax.devices()
+    if len(devs) < n:
+        raise RuntimeError(
+            f"{what} needs {n} devices; JAX sees {len(devs)} "
+            f"{devs[0].platform} device(s)"
+        )
+    return devs[:n]
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -50,4 +75,4 @@ def make_mesh_for(shape, axes) -> Mesh:
             "importing jax (see launch/dryrun.py)?"
         )
     grid = np.asarray(devs[:n]).reshape(shape)
-    return _mesh(grid, axes)
+    return Mesh(grid, axes, axis_types=(AxisType.Auto,) * len(axes))

@@ -19,6 +19,7 @@ import numpy as np
 import jax
 
 from repro.configs import ARCHS, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.embedder import LMEmbedder
 from repro.serving.service import SSSJService
 
@@ -88,6 +89,7 @@ def main() -> None:
     ap.add_argument("--lam", type=float, default=0.05)
     ap.add_argument("--dup-frac", type=float, default=0.25)
     args = ap.parse_args()
+    enable_compile_cache()
     run_service(
         args.arch, requests=args.requests, batch=args.batch, seq=args.seq,
         theta=args.theta, lam=args.lam, dup_frac=args.dup_frac,

@@ -109,7 +109,7 @@ class SSSJService:
         cfg = EngineConfig(
             theta=theta, lam=lam, capacity=capacity, d=dim,
             micro_batch=block, max_pairs=max_pairs, tile_k=tile_k,
-            block_q=block, block_w=block, chunk_d=min(dim, 128),
+            block_q=block, block_w=block,
         )
         self.engine = StreamEngine(cfg)
         self.embed_fn = embed_fn
@@ -284,7 +284,6 @@ class MultiTenantSSSJService:
             micro_batch=micro_batch, max_pairs=max_pairs,
             tile_k=tile_k or micro_batch * micro_batch,
             block_q=micro_batch, block_w=micro_batch,
-            chunk_d=min(dim, 128),
             eviction=eviction, quotas=quotas,
         )
         self.runtime = MultiTenantRuntime(

@@ -108,7 +108,7 @@ def test_compressed_psum_error_feedback():
     _run("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.distributed.sharding import shard_map  # jax 0.4/0.6 compat
+        from jax import shard_map
         from repro.launch.mesh import make_mesh_for
         from repro.train.grad_sync import compressed_psum, init_ef_state
 

@@ -56,7 +56,10 @@ def _random_state(rng, cap, eviction="oldest", n_lanes=K, t_now=10.0):
     uids[filled] = rng.permutation(n_fill).astype(np.int32)
     sids[filled] = rng.integers(0, n_lanes, n_fill).astype(np.int32)
     ts[kind == 1] = t_now - TAU - 1.0 - rng.random((kind == 1).sum())
-    ts[kind == 2] = t_now - TAU * rng.random((kind == 2).sum())
+    # live items stay live for every t_max a batch can reach (≤ 48 rows at
+    # 0.01 spacing → t_now + 0.48), so no cut point of a batch and no
+    # choice of t_max moves a slot across the horizon
+    ts[kind == 2] = t_now - (TAU - 0.5) * rng.random((kind == 2).sum())
     vecs = rng.standard_normal((cap, D)).astype(np.float32)
     vecs[~filled] = 0.0
     state = state._replace(
@@ -83,6 +86,7 @@ def _batch(rng, b, n_valid, t_now, uid0=1000):
 
 
 def _quotas(rng, cap):
+    # quota_partition refuses fewer slots than streams: callers draw cap ≥ K
     return jnp.asarray(quota_partition(cap, rng.random(K) + 0.25), jnp.int32)
 
 
@@ -91,6 +95,8 @@ def _quotas(rng, cap):
 # --------------------------------------------------------------------- #
 def _check_unique(seed, cap, b, eviction):
     rng = np.random.default_rng(seed)
+    if eviction == "quota":
+        cap = max(cap, K)
     ev = "quota" if eviction == "quota" else "oldest"
     state, _, t_now = _random_state(rng, cap, eviction=ev)
     n_valid = int(rng.integers(0, min(b, cap) + 1))
@@ -140,6 +146,8 @@ def _states_equal(a, b):
 
 def _check_split_invariance(seed, cap, b, eviction):
     rng = np.random.default_rng(seed)
+    if eviction == "quota":
+        cap = max(cap, K)
     ev = "quota" if eviction == "quota" else "oldest"
     state, kind, t_now = _random_state(rng, cap, eviction=ev)
     if eviction == "dead":
