@@ -43,7 +43,7 @@ overflow is *lower* under ``quota`` than under ``oldest``, and under
 
 ``--latency`` runs the open-loop arrival scenario (DESIGN.md §12):
 wall-clock Poisson arrivals replayed in real time against deadline
-flushes, with per-tenant admission→emission latency percentiles read off
+flushes, with per-tenant admission→return latency percentiles read off
 the metrics registry's log-bucket histograms.  Writes
 ``BENCH_latency.json`` (including the raw global histogram).
 
@@ -348,21 +348,21 @@ def _hist_delta(final: dict, base: dict) -> dict:
 
 
 def run_latency(smoke: bool = False):
-    """Open-loop arrival scenario: admission→emission latency histograms.
+    """Open-loop arrival scenario: admission→return latency histograms.
 
     Arrivals are scheduled on a wall clock (Poisson per tenant) and
-    replayed in real time; the runtime flushes on a fixed deadline
-    (``flush(final=True)``, the latency-deadline case), so each item's
-    latency = queueing until its deadline flush + device scan + D2H copy
-    landing on the host.  Percentiles come from the registry's log-bucket
-    histograms (``latency/admit_to_emit_s``, ``tenant/<k>/latency_s``) —
-    the same metrics a scraper would see — with warmup observations
-    subtracted via a baseline snapshot.
+    replayed in real time; the runtime flushes and drains on a fixed
+    deadline (``flush(final=True)``, the latency-deadline case), so each
+    item's latency = queueing until its deadline flush + device scan + D2H
+    copy + the drain returning its rows.  Percentiles come from the
+    registry's log-bucket histograms (``latency/admit_to_emit_s``,
+    ``tenant/<k>/latency_s``) — the same metrics a scraper would see — with
+    warmup observations subtracted via a baseline snapshot.
 
     Returns ``(rows, latency_histogram)`` — the delta histogram rides
     into ``BENCH_latency.json`` for offline analysis.
     """
-    from repro.obs import histogram_percentile
+    from repro.obs import PIPELINE_STAGES, histogram_percentile
 
     rows: List[Row] = []
     if smoke:
@@ -413,6 +413,7 @@ def run_latency(smoke: bool = False):
             now = t_sched
         while now >= next_deadline:
             rt.flush(final=True)
+            rt.drain_by_tenant()
             next_deadline += deadline_s
             now = time.perf_counter() - t0
         rt.submit(int(k), vec[None, :], np.asarray([t_sched]))
@@ -442,7 +443,7 @@ def run_latency(smoke: bool = False):
                         histogram_percentile(th, 0.50) * 1e3))
         rows.append(Row(f"latency/tenant/{k}/p99_ms",
                         histogram_percentile(th, 0.99) * 1e3))
-    for stage in ("admit", "coalesce", "h2d", "scan", "drain", "emit"):
+    for stage in PIPELINE_STAGES:
         rows.append(Row(f"latency/span/{stage}/time_s",
                         snap[f"span/{stage}/time_s"],
                         f"{snap[f'span/{stage}/calls']} calls"))
@@ -558,7 +559,7 @@ def main() -> None:
     ap.add_argument("--latency", action="store_true",
                     help="run the open-loop arrival scenario instead: "
                          "wall-clock Poisson arrivals, deadline flushes, "
-                         "per-tenant admission→emission latency histograms "
+                         "per-tenant admission→return latency histograms "
                          "from the metrics registry (DESIGN.md §12)")
     ap.add_argument("--json", default=None,
                     help=f"machine-readable output path (default {JSON_PATH}; "

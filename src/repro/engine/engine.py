@@ -44,7 +44,6 @@ from __future__ import annotations
 
 import concurrent.futures
 import dataclasses
-import time
 from typing import Callable, List, NamedTuple, Optional, Tuple
 
 import jax
@@ -52,7 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.similarity import time_horizon
-from ..obs import MetricsRegistry
+from ..obs import MetricsRegistry, Span, SpanTracer
 from ..kernels.sssj_join import (
     PairBuffer,
     compact_pairs,
@@ -452,6 +451,10 @@ class StreamEngineBase:
     them to numpy (double-buffered — device compute of the next push
     overlaps the copy of the previous one); ``drain_*`` only joins on the
     already-copied results.
+
+    ``tracer`` times the host stages (:mod:`repro.obs.spans`) into the
+    registry; ``spans_dispatched`` counts device dispatches and is the
+    ordinal each stage's annotation carries.
     """
 
     def __init__(
@@ -460,8 +463,8 @@ class StreamEngineBase:
         # cfg invariants are enforced by EngineConfig.__post_init__
         self.cfg = cfg
         self._next_uid = 0
-        # futures of host-materialized (bufs, masks, nvs, nbytes, t_done,
-        # fetch_s) records
+        # futures of host-materialized (bufs, masks, nvs, nbytes, wait_s,
+        # d2h_s) records, in dispatch order
         self._pending: List[concurrent.futures.Future] = []
         self._copier = concurrent.futures.ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="sssj-drain"
@@ -476,6 +479,8 @@ class StreamEngineBase:
         # compatibility view over the same snapshot
         self.registry = registry if registry is not None else MetricsRegistry()
         self.registry.register_collector(self._publish_metrics)
+        self.tracer = SpanTracer(self.registry)
+        self.spans_dispatched = 0
 
     def _global_capacity(self) -> int:
         return self.cfg.capacity
@@ -496,10 +501,15 @@ class StreamEngineBase:
         )
         self._next_uid += b
         self.n_items += b
-        self.state, self.telem, bufs, masks = self._step(
-            self.state, self.telem, qs, tqs, uqs, nvs
+        self.spans_dispatched += 1
+        n = self.spans_dispatched
+        with self.tracer.span("dispatch", n):
+            self.state, self.telem, bufs, masks = self._step(
+                self.state, self.telem, qs, tqs, uqs, nvs
+            )
+        self._pending.append(
+            self._copier.submit(self._fetch, bufs, masks, nvs, n)
         )
-        self._pending.append(self._copier.submit(self._fetch, bufs, masks, nvs))
         # the dense path would have fetched (mb, capacity) + (mb, mb) f32
         # score matrices per micro-batch
         mb = self.cfg.micro_batch
@@ -509,33 +519,40 @@ class StreamEngineBase:
         return uq
 
     @staticmethod
-    def _fetch(bufs: PairBuffer, masks, nvs: np.ndarray):
-        """Worker-thread D2H: materialize one push's device outputs.
+    def _fetch(bufs: PairBuffer, masks, nvs: np.ndarray, dispatch: int):
+        """Worker-thread D2H: materialize one dispatch's device outputs.
 
-        Stamps ``t_done`` (monotonic) when the copy lands — the moment
-        this batch's pairs become host-visible, which is what
-        admission→emission latency measures — plus the copy duration for
-        the ``drain`` pipeline span.
+        Times two stages under their annotations: ``device_wait``, until
+        the step's outputs are ready, and ``d2h``, their copy to host
+        memory.  The durations ride back with the record; the caller
+        thread records them (:meth:`_join`).
         """
-        t0 = time.monotonic()
-        host = jax.tree.map(np.asarray, bufs)
-        masks = np.asarray(masks)
+        with Span("device_wait", dispatch) as wait:
+            jax.block_until_ready((bufs, masks))
+        with Span("d2h", dispatch) as d2h:
+            host = jax.tree.map(np.asarray, bufs)
+            masks = np.asarray(masks)
         nbytes = sum(x.nbytes for x in host) + masks.nbytes
-        t_done = time.monotonic()
-        return host, masks, nvs, nbytes, t_done, t_done - t0
+        return host, masks, nvs, nbytes, wait.seconds, d2h.seconds
 
     # ------------------------------------------------------------------ #
-    def _observe_emission(self, t_done: float, fetch_s: float) -> None:
-        """Per-record drain hook (admission→emission latency attribution
-        in the multi-tenant runtime); records arrive in dispatch order."""
-
-    def _drain(self):
-        recs = [f.result() for f in self._pending]
+    def _join(self) -> list:
+        """Wait for the copy thread's record of every pending dispatch (the
+        ``flush_wait`` span) and record each one's copy-thread stages."""
+        with self.tracer.span("flush_wait", self.spans_dispatched):
+            recs = [f.result() for f in self._pending]
         self._pending.clear()
-        ua_all, ub_all, sc_all, mk_all = [], [], [], []
-        for bufs, masks, nvs, nbytes, t_done, fetch_s in recs:
+        for *_, nbytes, wait_s, d2h_s in recs:
             self.bytes_to_host += nbytes
-            self._observe_emission(t_done, fetch_s)
+            self.tracer.record("device_wait", wait_s)
+            self.tracer.record("d2h", d2h_s)
+        return recs
+
+    @staticmethod
+    def _assemble(recs: list):
+        """The pairs and row masks of joined records, in stream order."""
+        ua_all, ub_all, sc_all, mk_all = [], [], [], []
+        for bufs, masks, nvs, *_ in recs:
             n = np.asarray(bufs.n_pairs)
             n = n.reshape(n.shape[0], -1)             # (n_micro, n_segments)
             n_micro, n_seg = n.shape
@@ -569,7 +586,7 @@ class StreamEngineBase:
         when pair emission overflowed (it derives from level-1 counts,
         DESIGN.md §3).
         """
-        ua, ub, sc, mk = self._drain()
+        ua, ub, sc, mk = self._assemble(self._join())
         if return_masks:
             return ua, ub, sc, mk
         return ua, ub, sc

@@ -19,7 +19,7 @@ from .registry import (
     log_buckets,
     merge_disjoint,
 )
-from .spans import PIPELINE_STAGES, SpanTracer
+from .spans import PIPELINE_STAGES, Span, SpanTracer
 
 __all__ = [
     "Counter",
@@ -29,6 +29,7 @@ __all__ = [
     "LATENCY_BOUNDS_S",
     "MetricsRegistry",
     "PIPELINE_STAGES",
+    "Span",
     "SpanTracer",
     "histogram_percentile",
     "log_buckets",

@@ -76,8 +76,9 @@ def log_buckets(lo: float, hi: float, growth: float = 2.0) -> Tuple[float, ...]:
     return tuple(out)
 
 
-# admission→emission latency vocabulary: 10 µs … ~84 s in ×2 steps
-LATENCY_BOUNDS_S: Tuple[float, ...] = log_buckets(1e-5, 64.0, 2.0)
+# admission→return latency vocabulary: 10 µs … ~71 s in ×2^¼ steps (each
+# bucket 19% wide, so an interpolated percentile is good to a few percent)
+LATENCY_BOUNDS_S: Tuple[float, ...] = log_buckets(1e-5, 64.0, 2.0 ** 0.25)
 
 
 class Counter:

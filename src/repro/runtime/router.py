@@ -110,7 +110,7 @@ class RequestRouter:
 
         Returns ``(payload (n, ...), ts (n,), uids (n,), sids (n,),
         t_admit (n,))`` — ``t_admit`` is each row's monotonic admission
-        stamp, the anchor for admission→emission latency attribution
+        stamp, the anchor for admission→return latency attribution
         (DESIGN.md §12).  A partially-consumed head chunk stays queued
         with its cursor advanced, so micro-batch boundaries never reorder
         or drop rows.

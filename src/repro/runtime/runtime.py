@@ -38,8 +38,8 @@ boundaries, flush timing, and span size (tested property-style in
 from __future__ import annotations
 
 import dataclasses
-from collections import deque
-from typing import Any, Deque, Dict, Optional, Tuple
+import time
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -61,7 +61,7 @@ from ..engine.sharded import (
     window_axis,
 )
 from ..engine.window import init_window, push_with_overflow
-from ..obs import SpanTracer, merge_disjoint, publish_flat
+from ..obs import merge_disjoint, publish_flat
 from .router import RequestRouter, TenantBackpressure
 from .tenants import TenantTable
 
@@ -319,19 +319,18 @@ class MultiTenantRuntime(StreamEngineBase):
         self.state = self.engine.init_state(cfg, table)
         self.telem = self.engine.init_telemetry(cfg)
         self._step = self.engine.make_step(cfg, table, fused)
-        # observability (DESIGN.md §12): the engine registry (created by
-        # StreamEngineBase.__init__) is the single stats surface — the
-        # runtime adds router/tenant collectors, pipeline spans, and
-        # admission→emission latency histograms to the same instance
-        self.tracer = SpanTracer(self.registry)
+        # observability (DESIGN.md §12): the engine registry and span tracer
+        # (created by StreamEngineBase.__init__) are the single stats
+        # surface — the runtime adds router/tenant collectors and
+        # admission→return latency histograms to the same instance
         self._lat_hist = self.registry.histogram("latency/admit_to_emit_s")
         self._lat_by_tenant = [
             self.registry.histogram(f"tenant/{t}/latency_s")
             for t in range(table.n_tenants)
         ]
-        # (sids, t_admit) per dispatch, FIFO — drained records arrive in
-        # dispatch order (single copy worker), so attribution zips exactly
-        self._dispatch_meta: Deque[Tuple[np.ndarray, np.ndarray]] = deque()
+        # (sids, t_admit) per dispatch not yet returned; a drain joins every
+        # pending dispatch, so it returns the rows of all of them
+        self._dispatch_meta: List[Tuple[np.ndarray, np.ndarray]] = []
         self.registry.register_collector(self._publish_runtime_metrics)
         # uid → tenant map: a doubling-growth append buffer (4 B per item
         # ever admitted — see ROADMAP on tenant-aware state)
@@ -340,7 +339,6 @@ class MultiTenantRuntime(StreamEngineBase):
         self._mask_uid0 = 0          # first uid the next drain's mask covers
         self.padded_rows = 0         # inert rows in real micro-batches
         self.empty_micro_batches = 0  # span-fill micro-batches (all dead)
-        self.spans_dispatched = 0
         self.submitted_by_tenant: Dict[int, int] = {
             t: 0 for t in range(table.n_tenants)
         }
@@ -387,7 +385,8 @@ class MultiTenantRuntime(StreamEngineBase):
         if b == 0:
             return np.empty((0,), np.int32)
         uids = np.arange(self._next_uid, self._next_uid + b, dtype=np.int32)
-        with self.tracer.span("admit"):
+        # the rows ride in the next dispatch or a later one
+        with self.tracer.span("admit", self.spans_dispatched + 1):
             self.router.admit(tenant, data, ts, uids)  # all-or-nothing
         self._next_uid += b
         n = self._uid_tenant_n
@@ -411,7 +410,8 @@ class MultiTenantRuntime(StreamEngineBase):
         assert n <= rows
         n_real = -(-n // mb)                     # micro-batches with any data
         pad = rows - n
-        with self.tracer.span("coalesce"):
+        k = self.spans_dispatched + 1            # this dispatch's ordinal
+        with self.tracer.span("coalesce", k):
             if self.fused is not None:
                 pl = np.zeros((rows, self.fused.seq_len), np.int32)
             else:
@@ -430,16 +430,14 @@ class MultiTenantRuntime(StreamEngineBase):
             sq[:n] = sids
             nvs = np.clip(n - mb * np.arange(span), 0, mb).astype(np.int32)
 
-        with self.tracer.span("h2d"):
+        with self.tracer.span("h2d", k):
             args = (
                 jnp.asarray(pl.reshape(span, mb, -1)),
                 jnp.asarray(tq.reshape(span, mb)),
                 jnp.asarray(uq.reshape(span, mb)),
                 jnp.asarray(sq.reshape(span, mb)),
             )
-        with self.tracer.span("scan"):
-            # dispatch time only — jax executes asynchronously; device wall
-            # time hides in the drain span (see repro.obs.spans)
+        with self.tracer.span("dispatch", k):
             if self.fused is not None:
                 self.state, self.telem, bufs, masks = self._step(
                     self.fused.params, self.state, self.telem, *args, nvs
@@ -449,14 +447,16 @@ class MultiTenantRuntime(StreamEngineBase):
                     self.state, self.telem, *args, nvs
                 )
         self._dispatch_meta.append((sids, t_admit))
-        self._pending.append(self._copier.submit(self._fetch, bufs, masks, nvs))
+        self._pending.append(
+            self._copier.submit(self._fetch, bufs, masks, nvs, k)
+        )
         self.n_items += n
         # padding waste = inert rows inside *real* micro-batches (they ride
         # through the join); span-fill micro-batches are separate — their
         # strips are all dead, so they cost scan steps but no join work
         self.padded_rows += n_real * mb - n
         self.empty_micro_batches += self.span - n_real
-        self.spans_dispatched += 1
+        self.spans_dispatched = k
         # dense-equivalent traffic counts real micro-batches only (what the
         # dense path would actually have fetched for this data)
         self.bytes_dense_equiv += n_real * 4 * (
@@ -476,25 +476,38 @@ class MultiTenantRuntime(StreamEngineBase):
         rows_span = mb * self.span
         sent = 0
         while len(self.router) >= rows_span:
-            self._dispatch(*self.router.take(rows_span))
+            self._dispatch(*self._take(rows_span))
             sent += rows_span
         rem = len(self.router)
         take_n = rem if final else (rem // mb) * mb
         if take_n:
-            self._dispatch(*self.router.take(take_n))
+            self._dispatch(*self._take(take_n))
             sent += take_n
         return sent
+
+    def _take(self, n: int):
+        """Pop the next dispatch's rows from the router (the ``take``
+        span)."""
+        with self.tracer.span("take", self.spans_dispatched + 1):
+            return self.router.take(n)
 
     # ------------------------------------------------------------------ #
     def _tenant_of(self, uids: np.ndarray) -> np.ndarray:
         return self._uid_tenant_buf[:self._uid_tenant_n][uids]
 
-    def drain_arrays(self, return_masks: bool = False):
-        """As :meth:`StreamEngineBase.drain_arrays`, tracking the uid range
-        each drain's masks cover so per-tenant attribution stays aligned
-        however the caller mixes global and per-tenant drains."""
-        ua, ub, sc, mask = super().drain_arrays(return_masks=True)
+    def _rows(self, recs: list):
+        """Assemble joined records, tracking the uid range each drain's
+        masks cover so per-tenant attribution stays aligned however the
+        caller mixes global and per-tenant drains."""
+        ua, ub, sc, mask = self._assemble(recs)
         self._mask_uid0 += mask.shape[0]
+        return ua, ub, sc, mask
+
+    def drain_arrays(self, return_masks: bool = False):
+        """As :meth:`StreamEngineBase.drain_arrays`; observes the returned
+        rows' latency."""
+        ua, ub, sc, mask = self._rows(self._join())
+        self._observe_return()
         if return_masks:
             return ua, ub, sc, mask
         return ua, ub, sc
@@ -510,14 +523,20 @@ class MultiTenantRuntime(StreamEngineBase):
         masks, aligned with its dispatched uids in admission order.  Pair
         attribution uses ``uid_a``'s stream — the join's stream-equality
         mask guarantees ``uid_b`` agrees.
-        """
-        with self.tracer.span("emit"):
-            return self._drain_by_tenant(return_masks)
 
-    def _drain_by_tenant(
-        self, return_masks: bool = False
+        The wait on the copy thread is the ``flush_wait`` span; ``emit``
+        times what follows it: the grouping, and the latency observation
+        of the rows being returned.
+        """
+        recs = self._join()
+        with self.tracer.span("emit", self.spans_dispatched):
+            out = self._group_by_tenant(*self._rows(recs), return_masks)
+            self._observe_return()
+        return out
+
+    def _group_by_tenant(
+        self, ua, ub, sc, mask, return_masks: bool
     ) -> Dict[int, Tuple[np.ndarray, ...]]:
-        ua, ub, sc, mask = self.drain_arrays(return_masks=True)
         mask_uids = np.arange(
             self._mask_uid0 - mask.shape[0], self._mask_uid0, dtype=np.int64
         )
@@ -577,22 +596,21 @@ class MultiTenantRuntime(StreamEngineBase):
         return self.engine.global_capacity(self.cfg)
 
     # ------------------------------------------------------------------ #
-    def _observe_emission(self, t_done: float, fetch_s: float) -> None:
-        """Attribute one drained record's admission→emission latency.
+    def _observe_return(self) -> None:
+        """Observe admission→return latency, one observation per row, for
+        the rows a drain is handing the caller now.
 
-        Records leave :meth:`_drain` in dispatch order (single copy
-        worker, FIFO futures) and ``push()`` is disabled, so each record
-        pairs with exactly one ``(sids, t_admit)`` entry queued by
-        :meth:`_dispatch`.
+        A drain joins every pending dispatch (``push()`` is disabled, so
+        each one queued a ``(sids, t_admit)`` entry in :meth:`_dispatch`),
+        so the rows returned are those of every entry queued.
         """
-        self.tracer.record("drain", fetch_s)
-        if not self._dispatch_meta:     # pragma: no cover - defensive
-            return
-        sids, t_admit = self._dispatch_meta.popleft()
-        lat = np.maximum(t_done - t_admit, 0.0)
-        self._lat_hist.observe_many(lat)
-        for t in np.unique(sids):
-            self._lat_by_tenant[int(t)].observe_many(lat[sids == t])
+        t_return = time.monotonic()      # the router's admission clock
+        for sids, t_admit in self._dispatch_meta:
+            lat = np.maximum(t_return - t_admit, 0.0)
+            self._lat_hist.observe_many(lat)
+            for t in np.unique(sids):
+                self._lat_by_tenant[int(t)].observe_many(lat[sids == t])
+        self._dispatch_meta.clear()
 
     def _publish_runtime_metrics(self, reg) -> None:
         """Snapshot-time collector: router/runtime/per-tenant counters

@@ -334,23 +334,26 @@ class MultiTenantSSSJService:
         as :meth:`MultiTenantRuntime.flush`).  Pass ``final=True`` at end
         of stream or on a latency deadline to pad the tail out.  Returns
         ``{tenant: [(local_uid_newer, local_uid_older, score)]}`` for
-        tenants that emitted anything this flush.
+        tenants that emitted anything this flush.  The local-id mapping
+        and union-find are the ``group`` span.
         """
-        self.runtime.flush(final=final)
-        per = self.runtime.drain_by_tenant()
+        rt = self.runtime
+        rt.flush(final=final)
+        per = rt.drain_by_tenant()
         out: Dict[int, List[Tuple[int, int, float]]] = {}
         union = self.groups.union
         loc = self._local_of
-        for t, (ua, ub, sc) in per.items():
-            if ua.size == 0:
-                continue
-            pairs = [
-                (loc[a], loc[b], s)
-                for a, b, s in zip(ua.tolist(), ub.tolist(), sc.tolist())
-            ]
-            for a, b, _ in pairs:
-                union((t, a), (t, b))          # namespaced: (tenant, uid)
-            out[t] = pairs
+        with rt.tracer.span("group", rt.spans_dispatched):
+            for t, (ua, ub, sc) in per.items():
+                if ua.size == 0:
+                    continue
+                pairs = [
+                    (loc[a], loc[b], s)
+                    for a, b, s in zip(ua.tolist(), ub.tolist(), sc.tolist())
+                ]
+                for a, b, _ in pairs:
+                    union((t, a), (t, b))      # namespaced: (tenant, uid)
+                out[t] = pairs
         return out
 
     # ------------------------------------------------------------------ #
