@@ -55,7 +55,6 @@ from ..obs import MetricsRegistry, Span, SpanTracer
 from ..kernels.sssj_join import (
     PairBuffer,
     compact_pairs,
-    concat_candidates,
     merge_candidates,
     sssj_join_candidates,
     sssj_join_tiles,
@@ -375,9 +374,9 @@ def make_micro_step(
             )
             js = sssj_join_candidates(q, q, tq, tq, uq, uq, **ckw, **self_kw)
             cs = js.cands if self_mask is None else self_mask(js.cands)
-            buf = merge_candidates(
-                concat_candidates(jw.cands, cs), max_pairs=cfg.max_pairs
-            )
+            # window tiles first, then the self-join's: each source keeps
+            # the slot layout its join wrote (no slab is copied)
+            buf = merge_candidates((jw.cands, cs), max_pairs=cfg.max_pairs)
             row_mask = jw.row_mask | js.row_mask
             it_win = jw.iters
             gate_stats = (

@@ -2,7 +2,6 @@ from .compact import (  # noqa: F401
     PairBuffer,
     PairCandidates,
     compact_pairs,
-    concat_candidates,
     merge_candidates,
     tile_candidates,
     tile_emit_counts,

@@ -35,7 +35,8 @@ PCIe boundary.
 
 from __future__ import annotations
 
-from typing import NamedTuple
+import math
+from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -44,7 +45,6 @@ __all__ = [
     "PairBuffer",
     "PairCandidates",
     "compact_pairs",
-    "concat_candidates",
     "merge_candidates",
     "tile_candidates",
     "tile_emit_counts",
@@ -57,13 +57,15 @@ class PairCandidates(NamedTuple):
     A *segment* is a kernel tile (or, at the sharded engine's second merge
     level, one device's compacted buffer).  Each segment holds its first
     ``kept ≤ K`` emitted pairs in stream (row-major) order; slots past
-    ``kept`` are inert (``uid = -1``, ``score = 0``).
+    ``kept`` are inert (``uid = -1``, ``score = 0``).  A segment's K slots
+    are its trailing dims in row-major order: ``(K,)`` from the jnp joins,
+    ``(n_rows, 128)`` slabs from the Pallas kernel.
     """
 
-    uid_a: jax.Array    # (S, K) i32 — query-side uid, -1 in unused slots
-    uid_b: jax.Array    # (S, K) i32 — window-side uid
-    score: jax.Array    # (S, K) f32 — decayed similarity, 0 in unused slots
-    kept: jax.Array     # (S,) i32 — valid entries per segment (≤ K)
+    uid_a: jax.Array    # (S, *slots) i32 — query-side uid, -1 in unused slots
+    uid_b: jax.Array    # (S, *slots) i32 — window-side uid
+    score: jax.Array    # (S, *slots) f32 — decayed similarity, 0 in unused
+    kept: jax.Array     # (S,) i32 — valid entries per segment (≤ K slots)
     emitted: jax.Array  # (S,) i32 — true ≥θ count per segment (≥ kept)
 
 
@@ -83,15 +85,15 @@ class PairBuffer(NamedTuple):
         return (self.n_dropped + self.n_dropped_tile) > 0
 
 
-def _segmented_take(counts: jax.Array, seg_cap: int, out_cap: int):
+def _segmented_take(counts: jax.Array, out_cap: int):
     """Destination plan for packing ragged segments into a dense prefix.
 
-    Given per-segment valid counts (each ≤ ``seg_cap``), returns
-    ``(src, valid, total)`` where ``src[s]`` is the flat index (into the
-    ``(S·seg_cap,)`` row-major segment buffer) of the s-th surviving entry,
-    ``valid[s]`` marks ``s < min(total, out_cap)``, and ``total`` is the sum
-    of counts.  Pure scan + binary search + gather — O(S + out_cap·log S),
-    no sort, regardless of how many elements the segments describe.
+    Given per-segment valid counts, returns ``(seg, rank, valid, total)``:
+    the s-th surviving entry is slot ``rank[s]`` of segment ``seg[s]``,
+    ``valid[s]`` marks ``s < min(total, out_cap)``, and ``total`` is the
+    sum of counts.  Pure scan + binary search + gather — O(S + out_cap·log
+    S), no sort, regardless of how many elements the segments describe.
+    Ranks past the survivors point at slot 0 of the last segment.
     """
     counts = counts.astype(jnp.int32)
     n_seg = counts.shape[0]
@@ -104,23 +106,53 @@ def _segmented_take(counts: jax.Array, seg_cap: int, out_cap: int):
     ).astype(jnp.int32)
     base = cum[seg] - counts[seg]                              # exclusive scan
     valid = s < jnp.minimum(total, out_cap)
-    src = seg * seg_cap + (s - base)
-    return jnp.where(valid, src, 0), valid, total
+    return seg, jnp.where(valid, s - base, 0), valid, total
 
 
-def merge_candidates(cands: PairCandidates, *, max_pairs: int) -> PairBuffer:
+def _gather_slots(x: jax.Array, seg: jax.Array, rank: jax.Array) -> jax.Array:
+    """``x[seg, slot rank]`` for a ``(S, *slots)`` buffer, the within-segment
+    rank unravelled row-major over the trailing dims as they are: ``(K,)``
+    from the jnp joins and the sharded merge, ``(n_rows, 128)`` slabs from
+    the Pallas kernel — never reshaped, so a slab is read where the kernel
+    wrote it."""
+    idx = jnp.unravel_index(rank, x.shape[1:])
+    return x[(seg,) + tuple(i.astype(jnp.int32) for i in idx)]
+
+
+def merge_candidates(
+    sources: PairCandidates | Sequence[PairCandidates], *, max_pairs: int
+) -> PairBuffer:
     """Level-2 merge: ragged per-segment candidates → packed pair buffer.
 
-    Survivors are the earliest pairs in (segment, within-segment) order;
-    everything lost — here to ``max_pairs`` or upstream to per-segment
-    capacity — is counted, never silent.
+    ``sources`` is one :class:`PairCandidates` or a sequence of them in
+    segment order (the window join's tiles, then the self-join's); each
+    keeps the slot layout its join wrote.  The plan runs over the
+    concatenated per-segment counts; every output rank gathers from each
+    source and keeps the one that holds it.  Survivors are the earliest
+    pairs in (segment, within-segment) order; everything lost — here to
+    ``max_pairs`` or upstream to per-segment capacity — is counted, never
+    silent.
     """
-    n_seg, seg_cap = cands.uid_a.shape
-    kept = jnp.minimum(cands.kept.astype(jnp.int32), seg_cap)
-    src, valid, total = _segmented_take(kept, seg_cap, max_pairs)
-    uid_a = jnp.where(valid, cands.uid_a.reshape(-1)[src], -1).astype(jnp.int32)
-    uid_b = jnp.where(valid, cands.uid_b.reshape(-1)[src], -1).astype(jnp.int32)
-    score = jnp.where(valid, cands.score.reshape(-1)[src], 0.0).astype(jnp.float32)
+    if isinstance(sources, PairCandidates):
+        sources = (sources,)
+    kept = jnp.concatenate([
+        jnp.minimum(c.kept.astype(jnp.int32), math.prod(c.uid_a.shape[1:]))
+        for c in sources
+    ])
+    emitted = jnp.concatenate([c.emitted for c in sources])
+    seg, rank, valid, total = _segmented_take(kept, max_pairs)
+    uid_a = jnp.full((max_pairs,), -1, jnp.int32)
+    uid_b = jnp.full((max_pairs,), -1, jnp.int32)
+    score = jnp.zeros((max_pairs,), jnp.float32)
+    lo = 0
+    for c in sources:
+        n_seg = c.uid_a.shape[0]
+        here = valid & (seg >= lo) & (seg < lo + n_seg)
+        local = jnp.clip(seg - lo, 0, n_seg - 1)
+        uid_a = jnp.where(here, _gather_slots(c.uid_a, local, rank), uid_a)
+        uid_b = jnp.where(here, _gather_slots(c.uid_b, local, rank), uid_b)
+        score = jnp.where(here, _gather_slots(c.score, local, rank), score)
+        lo += n_seg
     n_pairs = jnp.minimum(total, max_pairs).astype(jnp.int32)
     return PairBuffer(
         uid_a=uid_a,
@@ -128,14 +160,8 @@ def merge_candidates(cands: PairCandidates, *, max_pairs: int) -> PairBuffer:
         score=score,
         n_pairs=n_pairs,
         n_dropped=(total - n_pairs).astype(jnp.int32),
-        n_dropped_tile=jnp.sum(cands.emitted - kept).astype(jnp.int32),
+        n_dropped_tile=jnp.sum(emitted - kept).astype(jnp.int32),
     )
-
-
-def concat_candidates(*cands: PairCandidates) -> PairCandidates:
-    """Stack candidate sets (e.g. window join + self join) along the
-    segment axis; all must share the same per-segment capacity K."""
-    return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *cands)
 
 
 # --------------------------------------------------------------------- #

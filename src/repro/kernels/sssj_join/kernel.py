@@ -470,16 +470,17 @@ def sssj_join_candidates_kernel_call(
 ) -> tuple[jax.Array, ...]:
     """Hierarchical (level-1) pallas_call; no dense ``(Q, W)`` output exists.
 
-    Returns ``(uid_a, uid_b (nQ, nW, tile_k) i32 — -1 in unused slots,
-    score (nQ, nW, tile_k) f32, emitted (nQ, nW) i32 true per-tile ≥ θ
-    counts, row_hits (nQ, BQ) i32 0/1, iters (nQ, nW) i32)``.  Each tile's
-    slots hold its first ``min(emitted, tile_k)`` pairs in within-tile
-    row-major order.
+    Returns ``(uid_a, uid_b (nQ, nW, n_rows, 128) i32 — -1 in unused
+    slots, score (nQ, nW, n_rows, 128) f32, emitted (nQ, nW) i32 true
+    per-tile ≥ θ counts, row_hits (nQ, BQ) i32 0/1, iters (nQ, nW) i32)``
+    with ``n_rows = ceil(tile_k / 128)``.  Each tile's slab holds its first
+    ``min(emitted, tile_k)`` pairs in within-tile row-major order; slots
+    past ``tile_k`` are inert.  The slabs are returned as the kernel wrote
+    them: flattening their rows would relayout them under the (8, 128)
+    HBM tiling.
 
     The multi-tenant lanes (all four or none) ride as extra inputs in the
-    layouts of their timestamp counterparts.  The kernel writes candidate
-    slabs of ``ceil(tile_k / 128)`` rows of 128 slots; slots past
-    ``tile_k`` are inert and cut off here.
+    layouts of their timestamp counterparts.
     """
     Q, d = q.shape
     W, _ = w.shape
@@ -539,11 +540,8 @@ def sssj_join_candidates_kernel_call(
         name=CANDIDATE_KERNEL,
     )(*inputs)
 
-    def slots(x):
-        return x.reshape(nq, nw, n_rows * LANES)[..., :tile_k]
-
     return (
-        slots(ua), slots(ub), slots(score),
+        ua, ub, score,
         emitted.reshape(grid),
         row_hits.reshape(nq, block_q),
         iters.reshape(grid),

@@ -17,7 +17,8 @@ Two join surfaces:
   * :func:`sssj_join_candidates` — hierarchical emission (DESIGN.md §3):
     per-tile ``(tile_k,)`` candidate buffers with true-emit counts and a
     per-row hit mask.  Three interchangeable implementations produce
-    bit-identical candidate buffers:
+    bit-identical candidate buffers (the Pallas kernel's slots as the
+    ``(ceil(tile_k / 128), 128)`` slabs it writes, the others' as rows):
 
       - ``"pallas"`` — the level-1 select inside the TPU kernel
         (``kernel.sssj_join_candidates_kernel_call``); the dense tile
@@ -393,11 +394,13 @@ def sssj_join_candidates(
                 gate=gate,
             )
         )
+        # merging the leading (nq, nw) dims keeps each (n_rows, 128) slab
+        # where the kernel wrote it: a bitcast under the TPU's tiling
         t = nq * nw
         cands = PairCandidates(
-            uid_a=ua.reshape(t, tile_k),
-            uid_b=ub.reshape(t, tile_k),
-            score=score.reshape(t, tile_k),
+            uid_a=ua.reshape((t,) + ua.shape[2:]),
+            uid_b=ub.reshape((t,) + ub.shape[2:]),
+            score=score.reshape((t,) + score.shape[2:]),
             kept=jnp.minimum(emitted, tile_k).reshape(t),
             emitted=emitted.reshape(t),
         )

@@ -28,6 +28,7 @@ except ImportError:
     HAVE_HYPOTHESIS = False
 
 from repro.kernels.sssj_join import (  # noqa: E402
+    PairCandidates,
     compact_pairs,
     merge_candidates,
     sssj_join_candidates,
@@ -61,6 +62,16 @@ def _dense_truth(scores, uq, uw):
     return {
         (int(uq[a]), int(uw[b])): float(s[a, b]) for a, b in zip(qi, wi)
     }
+
+
+def _slots(x, tile_k):
+    """A candidate buffer's first ``tile_k`` slots per segment, row-major
+    over whatever slot layout its join wrote (``(K,)`` rows or the Pallas
+    kernel's ``(n_rows, 128)`` slabs); slots past ``tile_k`` must be inert."""
+    x = np.asarray(x)
+    flat = x.reshape(x.shape[0], -1)
+    assert (flat[:, tile_k:] == (-1 if x.dtype.kind == "i" else 0)).all()
+    return flat[:, :tile_k]
 
 
 def _buffer_pairs(buf):
@@ -189,14 +200,20 @@ def test_kernel_candidates_match_jnp_mirrors(seed, tile_k, theta):
     ref = sssj_join_candidates(q, w, tq, tw, uq, uw, impl="dense", **kw)
     for impl in ("scan", "pallas"):
         got = sssj_join_candidates(q, w, tq, tw, uq, uw, impl=impl, **kw)
-        for name in ("uid_a", "uid_b", "kept", "emitted"):
+        for name in ("uid_a", "uid_b"):
+            np.testing.assert_array_equal(
+                _slots(getattr(got.cands, name), tile_k),
+                _slots(getattr(ref.cands, name), tile_k),
+                err_msg=f"{impl}/{name}",
+            )
+        for name in ("kept", "emitted"):
             np.testing.assert_array_equal(
                 np.asarray(getattr(got.cands, name)),
                 np.asarray(getattr(ref.cands, name)),
                 err_msg=f"{impl}/{name}",
             )
         np.testing.assert_allclose(
-            np.asarray(got.cands.score), np.asarray(ref.cands.score),
+            _slots(got.cands.score, tile_k), _slots(ref.cands.score, tile_k),
             atol=1e-5, err_msg=f"{impl}/score",
         )
         np.testing.assert_array_equal(
@@ -374,3 +391,103 @@ def test_ref_path_matches_on_subblock_inputs(rng):
     buf = merge_candidates(jc.cands, max_pairs=64)
     assert _buffer_pairs(buf) == pytest.approx(_dense_truth(scores, uq, uw))
     assert int(buf.n_dropped) == 0 and int(buf.n_dropped_tile) == 0
+
+
+def _ragged_source(rng, n_seg, tile_k, slab, uid0):
+    """Random level-1 candidates: each segment keeps a random prefix of
+    ``tile_k`` slots (sometimes none); a full segment emitted more than it
+    kept, and the last one is always full; slots past ``kept`` are inert.
+    ``slab`` lays the slots out as the Pallas kernel writes them,
+    ``(ceil(tile_k/128), 128)``; otherwise as ``(tile_k,)`` rows."""
+    n_slots = -(-tile_k // 128) * 128 if slab else tile_k
+    kept = rng.integers(0, tile_k + 1, n_seg).astype(np.int32)
+    kept[rng.random(n_seg) < 0.25] = 0
+    kept[rng.random(n_seg) < 0.2] = tile_k
+    kept[-1] = tile_k                      # every source loses some pairs
+    emitted = kept + np.where(
+        kept == tile_k, rng.integers(1, 5, n_seg), 0
+    ).astype(np.int32)
+    live = np.arange(n_slots)[None, :] < kept[:, None]
+    uids = uid0 + np.arange(n_seg * n_slots, dtype=np.int32)
+    uid_a = np.where(live, uids.reshape(n_seg, n_slots), -1)
+    uid_b = np.where(live, uid_a // 3, -1)
+    score = np.where(live, rng.random((n_seg, n_slots)), 0.0)
+    shape = (n_seg, n_slots // 128, 128) if slab else (n_seg, n_slots)
+    return PairCandidates(
+        uid_a=jnp.asarray(uid_a.astype(np.int32).reshape(shape)),
+        uid_b=jnp.asarray(uid_b.astype(np.int32).reshape(shape)),
+        score=jnp.asarray(score.astype(np.float32).reshape(shape)),
+        kept=jnp.asarray(kept),
+        emitted=jnp.asarray(emitted),
+    )
+
+
+def _concat_then_merge(sources, max_pairs):
+    """The single-buffer semantics in numpy: flatten every segment's slots,
+    concatenate the sources' segments, keep the earliest ``max_pairs``
+    entries in (segment, slot) order."""
+    ua, ub, sc, tile_loss = [], [], [], 0
+    for c in sources:
+        n_seg = c.kept.shape[0]
+        flat = [np.asarray(x).reshape(n_seg, -1) for x in c[:3]]
+        kept = np.minimum(np.asarray(c.kept), flat[0].shape[1])
+        tile_loss += int((np.asarray(c.emitted) - kept).sum())
+        for seg in range(n_seg):
+            ua.extend(flat[0][seg, :kept[seg]])
+            ub.extend(flat[1][seg, :kept[seg]])
+            sc.extend(flat[2][seg, :kept[seg]])
+    n_pairs = min(len(ua), max_pairs)
+    return (np.asarray(ua[:n_pairs], np.int32),
+            np.asarray(ub[:n_pairs], np.int32),
+            np.asarray(sc[:n_pairs], np.float32),
+            n_pairs, len(ua) - n_pairs, tile_loss)
+
+
+# sources as (n_seg, tile_k, slab); ``cut`` places max_pairs this many
+# entries into the last source (None: a budget that holds everything)
+_MERGE_CASES = {
+    "slab": ([(12, 256, True), (1, 256, True)], None),
+    "flat": ([(9, 64, False), (1, 64, False)], None),
+    "mixed": ([(10, 128, True), (2, 128, False), (3, 128, True)], None),
+    "tile_k_not_lane_multiple": ([(8, 200, True), (1, 200, False)], None),
+    "overflow_in_second": ([(6, 130, True), (4, 130, True)], 37),
+    "single_bare_source": ([(7, 96, False)], None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_MERGE_CASES))
+def test_multi_source_merge_equals_concat_then_merge(case):
+    """Merging the sources where their joins wrote them equals merging
+    their concatenation, pair for pair, with the same pair and drop
+    counts — across slot layouts, source counts and a budget that runs out
+    inside a later source."""
+    import jax
+
+    specs, cut = _MERGE_CASES[case]
+    rng = np.random.default_rng(sorted(_MERGE_CASES).index(case))
+    sources, uid0 = [], 0
+    for n_seg, tile_k, slab in specs:
+        sources.append(_ragged_source(rng, n_seg, tile_k, slab, uid0))
+        uid0 += 1 << 20
+    if cut is None:
+        max_pairs = 4096
+    else:
+        before = sum(int(np.asarray(c.kept).sum()) for c in sources[:-1])
+        assert int(np.asarray(sources[-1].kept).sum()) > cut
+        max_pairs = before + cut
+    arg = sources[0] if len(sources) == 1 else tuple(sources)
+    buf = jax.jit(lambda s: merge_candidates(s, max_pairs=max_pairs))(arg)
+    ua, ub, sc, n_pairs, n_dropped, tile_loss = _concat_then_merge(
+        sources, max_pairs
+    )
+    assert int(buf.n_pairs) == n_pairs > 0
+    assert int(buf.n_dropped) == n_dropped
+    assert int(buf.n_dropped_tile) == tile_loss
+    if cut is not None:
+        assert n_dropped > 0
+    np.testing.assert_array_equal(np.asarray(buf.uid_a)[:n_pairs], ua)
+    np.testing.assert_array_equal(np.asarray(buf.uid_b)[:n_pairs], ub)
+    np.testing.assert_array_equal(np.asarray(buf.score)[:n_pairs], sc)
+    assert (np.asarray(buf.uid_a)[n_pairs:] == -1).all()
+    assert (np.asarray(buf.uid_b)[n_pairs:] == -1).all()
+    assert (np.asarray(buf.score)[n_pairs:] == 0.0).all()

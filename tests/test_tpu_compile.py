@@ -10,10 +10,17 @@ them, and check that each kernel is in the compiled program as a
 ``tpu_custom_call``.  Nothing runs, so nothing here speaks to results or
 times.
 
+The scan step is also read for what the compiler built around the
+kernels: no instruction of the step may copy, pad or flatten the
+kernel's level-1 candidate slabs on their way to the merge.
+
 The topology is described inside a fixture (never at import): only one
 process may hold the TPU library, and under pytest-xdist every worker
 imports this file.
 """
+
+import math
+import re
 
 import numpy as np
 import pytest
@@ -57,7 +64,7 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture
+@pytest.fixture(scope="module")
 def no_persistent_cache():
     """A described-chip compile is written to the persistent cache but can
     never be read back without the chip; keep the cache out of it."""
@@ -123,7 +130,10 @@ def test_strip_gate_compiles_over_full_window(one_chip, no_persistent_cache):
     assert GATE_KERNEL in tpu_kernels(compiled.as_text())
 
 
-def test_multi_tenant_scan_step_compiles(one_chip, no_persistent_cache):
+@pytest.fixture(scope="module")
+def scan_step_hlo(one_chip, no_persistent_cache):
+    """Compiled HLO text of the multi-tenant scan step: 8 tenants, a span of
+    4 micro-batches, the Pallas join and gate at the full window."""
     thetas = np.linspace(0.85, 0.95, 8)
     lam = float(np.log(1 / 0.85) / (W // 2))
     table = TenantTable(thetas, [lam] * 8)
@@ -142,9 +152,73 @@ def test_multi_tenant_scan_step_compiles(one_chip, no_persistent_cache):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     step = make_tenant_batch_step(cfg, table)
-    compiled = step.lower(
+    return step.lower(
         state, telem, S((span, B, D), jnp.float32), S((span, B), jnp.float32),
         S((span, B), jnp.int32), S((span, B), jnp.int32), S((span,), jnp.int32),
-    ).compile()
-    kernels = tpu_kernels(compiled.as_text())
+    ).compile().as_text()
+
+
+def test_multi_tenant_scan_step_compiles(scan_step_hlo):
+    kernels = tpu_kernels(scan_step_hlo)
     assert {CANDIDATE_KERNEL, GATE_KERNEL} <= kernels, kernels
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) .*\{$")
+_ARRAY_INSTR = re.compile(
+    r"^\s+(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\((.*)$"
+)
+_WHILE_BODY = re.compile(r"\bwhile\(.*\bbody=%([\w.-]+)")
+_KERNEL_OUT = re.compile(rf"%{CANDIDATE_KERNEL}(?:\.\d+)?\)")
+
+
+def _top_level_arrays(hlo_text: str):
+    """``(name, dims, opcode, rest of line)`` of every array-valued
+    instruction in the entry computation and in the while bodies (the
+    scan over micro-batches and the loops inside it) — fusions' own
+    internals are left out, since they live in registers and VMEM."""
+    comps, entry, name = {}, None, None
+    for line in hlo_text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+            if line.startswith("ENTRY "):
+                entry = name
+        elif name is not None:
+            comps[name].append(line)
+    bodies = {m.group(1) for m in _WHILE_BODY.finditer(hlo_text)}
+    for comp in {entry} | bodies:
+        for line in comps[comp]:
+            m = _ARRAY_INSTR.match(line)
+            if m:
+                dims = tuple(int(x) for x in m.group(2).split(",") if x)
+                yield m.group(1), dims, m.group(3), m.group(4)
+
+
+def test_scan_step_merges_slabs_in_place(scan_step_hlo):
+    """Every full-window-sized result of the step is the ring itself (a
+    parameter or its scatter update), the candidate kernel's slab output,
+    or a bitcast or tuple element of those: the level-2 merge gathers its
+    ``max_pairs`` entries from the slabs where the kernel wrote them, with
+    no relayout copy, no concatenation with the self-join's segment and
+    no flattening."""
+    big = (W // B) * TILE_K
+    ring = (W, D)
+    slabs, offending = 0, []
+    for name, dims, opcode, rest in _top_level_arrays(scan_step_hlo):
+        if math.prod(dims) < big:
+            continue
+        if opcode == "get-tuple-element" and _KERNEL_OUT.match(rest):
+            slabs += 1
+        elif opcode in ("parameter", "get-tuple-element", "bitcast"):
+            pass
+        elif dims == ring and opcode in ("fusion", "scatter",
+                                         "dynamic-update-slice"):
+            pass
+        else:
+            offending.append(f"%{name} = {opcode} {list(dims)}")
+    assert slabs == 3, f"expected the kernel's 3 candidate slabs, saw {slabs}"
+    assert not offending, (
+        "slab-sized instructions besides the ring and the kernel's slabs: "
+        + "; ".join(offending)
+    )
