@@ -1,6 +1,8 @@
 """The candidate kernel's share of its roofline: the least time the chip
 could take for the work an exact join must do (bench.roofline), over the
-kernel's device time, for the micro-batches of the traced window."""
+kernel's device time, for the micro-batches of the traced window.  A ring
+split over ``shards`` chips splits that work: each chip's share of the
+least time, over the kernel's mean device time per chip."""
 
 import sys
 
@@ -19,4 +21,4 @@ def read(r):
                                        roofline.peaks(r.device_kind))
     print(f"cand_roofline: bound by {bound}; least {t_min.sum():.6f} s "
           f"over {len(r.batches)} micro-batches", file=sys.stderr)
-    return 100.0 * float(t_min.sum()) / s
+    return 100.0 * float(t_min.sum()) / r.cfg.get("shards", 1) / s

@@ -14,6 +14,7 @@ one matmul per block at ``HIGHEST`` precision, and every entry within
 
 from __future__ import annotations
 
+import collections
 from functools import partial
 
 import jax
@@ -61,14 +62,17 @@ def _block_hits(key_row, key_anchor, idx, anchor, noise, q, gq, kq, thq,
 
 def reference_pairs(plan: gen.Plan, cfg: dict, rows: np.ndarray,
                     admitted: np.ndarray, block: int,
-                    n_query: int, precision: str = "f32") -> dict:
+                    n_query: int, precision: str = "f32",
+                    devices=None) -> dict:
     """``{g: {j: score}}`` for each query row ``g`` of ``rows``: every
     earlier admitted arrival of its tenant scoring at least θ - WIDE.
 
     ``admitted (m,)`` marks the arrivals ``[0, m)`` the service took;
     ``n_query`` fixes the padded query count (one compiled program).
     ``precision="bf16"`` is the control: the same brute force with
-    bfloat16 inputs."""
+    bfloat16 inputs.  ``devices`` share the blocks of earlier arrivals
+    round-robin, each scoring its blocks while the others score theirs
+    (default: JAX's default device alone)."""
     rows = np.sort(np.asarray(rows, np.int64))
     if rows.size > n_query:
         raise ValueError(f"{rows.size} query rows, room for {n_query}")
@@ -78,12 +82,14 @@ def reference_pairs(plan: gen.Plan, cfg: dict, rows: np.ndarray,
     gq = np.concatenate([rows, pad])
     kq = plan.tenant[gq]
     q = gen.rows_at(plan, gq)
+    qs = [q] if devices is None else [jax.device_put(q, d) for d in devices]
     thq = th[kq]
     lamq = np.full(n_query, lam, np.float32)
     m = admitted.size
     out = {int(g): {} for g in rows}
-    top = int(rows.max()) + 1 if rows.size else 0
-    for lo in range(0, top, block):
+    few = min(_FEW_ROWS, block)
+
+    def launch(lo, q_dev, max_rows):
         key_row, key_anchor, idx, anchor, noise = gen.block_args(plan, lo,
                                                                  block)
         hi = min(lo + block, m)
@@ -91,14 +97,16 @@ def reference_pairs(plan: gen.Plan, cfg: dict, rows: np.ndarray,
         aw = np.zeros(block, bool)
         tw[:hi - lo] = plan.tenant[lo:hi]
         aw[:hi - lo] = admitted[lo:hi]
-        for max_rows in (min(_FEW_ROWS, block), block):
-            n_row, n_hit, j, c, s = _block_hits(
-                key_row, key_anchor, idx, anchor, noise, q,
-                gq.astype(np.int32), kq, thq, lamq, tw, aw, d=plan.d,
-                precision=precision, max_rows=max_rows)
-            n_row, n_hit = int(n_row), int(n_hit)
-            if n_row <= max_rows:
-                break
+        return _block_hits(
+            key_row, key_anchor, idx, anchor, noise, q_dev,
+            gq.astype(np.int32), kq, thq, lamq, tw, aw, d=plan.d,
+            precision=precision, max_rows=max_rows)
+
+    def collect(lo, q_dev, hits):
+        n_row, n_hit, j, c, s = hits
+        if int(n_row) > few:
+            n_row, n_hit, j, c, s = launch(lo, q_dev, block)
+        n_hit = int(n_hit)
         if n_hit > _MAX_HITS:
             raise RuntimeError(f"{n_hit} reference entries in one block; "
                                f"room for {_MAX_HITS}")
@@ -106,6 +114,16 @@ def reference_pairs(plan: gen.Plan, cfg: dict, rows: np.ndarray,
         for jj, cc, ss in zip(j.tolist(), c.tolist(), s.tolist()):
             if cc < rows.size:
                 out[int(gq[cc])][jj] = ss
+
+    top = int(rows.max()) + 1 if rows.size else 0
+    pending = collections.deque()
+    for b, lo in enumerate(range(0, top, block)):
+        q_dev = qs[b % len(qs)]
+        pending.append((lo, q_dev, launch(lo, q_dev, few)))
+        if len(pending) == len(qs):
+            collect(*pending.popleft())
+    while pending:
+        collect(*pending.popleft())
     return out
 
 

@@ -1,12 +1,14 @@
 #!/usr/bin/env python3
-"""Benchmark of the multi-tenant similarity-join service on one chip.
+"""Benchmark of the multi-tenant similarity-join service on one chip or a
+mesh of them.
 
     python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 ``<name>`` is a cell of ``BENCHMARK.json``: a configuration
 (``bench/configs/<config>.json``) under a traffic mix
 (``bench/traffic/<mix>.json``).  A run builds the service the configuration
-describes, fills its ring with the arrivals that precede the window
+describes (its ring split over ``shards`` chips where the configuration
+has that key), fills its ring with the arrivals that precede the window
 (``bench/prefill.py``), warms up, drives ``submit``/``flush`` for
 ``--seconds`` with the mix's client (``bench/client.py``), then checks a
 seeded sample of the window's rows against the plain reference
@@ -96,6 +98,17 @@ def chip(chips: int):
     if len(devs) < chips:
         raise NoChip(f"the cell needs {chips} chips, JAX sees {len(devs)}")
     return devs
+
+
+def mesh_of(cfg: dict, devs):
+    """The mesh a configuration's ring is split over: its ``shards``
+    devices on the program's window axis ``data``; ``None`` for one."""
+    import jax
+
+    shards = cfg.get("shards", 1)
+    if shards == 1:
+        return None
+    return jax.make_mesh((shards,), ("data",), devices=devs[:shards])
 
 
 def _log(msg: str) -> None:
@@ -194,7 +207,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     from bench.metrics import Readings, read_metric
 
     wl, cfg, mix = cell["workload"], cell["cfg"], cell["mix"]
-    devs = chips(wl["chips"])
+    devs = chips(wl["chips"])[:wl["chips"]]
     dev = devs[0]
     _log(f"device {dev.device_kind} x{len(devs)}")
     cl = mix["client"]
@@ -220,7 +233,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
                                     / chunk) + 1)
     plan = gen.make_plan(cfg, mix, seed, cap + n_pool)
     _log(f"plan of {cap} + {n_pool} arrivals")
-    svc = prefill.build_service(cfg)
+    svc = prefill.build_service(cfg, mesh=mesh_of(cfg, devs))
     prefill.install(svc, plan, cap - warm, block)
     _log("ring filled")
     warm_pool = client.Pool.of(
@@ -256,7 +269,9 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     tail = svc.flush(final=True)
     drained = svc.snapshot()
     stats = svc.stats()
-    peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
+    # the fullest chip's peak
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in devs]
+    peak = max((p for p in peaks if p is not None), default=None)
     _log(f"window closed: {res.returned} rows in {res.t_end - res.t0:.3f} s; "
          f"{watch}; flushes (ms): "
          f"{' '.join(str(round(1e3 * x)) for x in res.flush_s)}")
@@ -278,11 +293,20 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     gc.collect()
 
     ref = reference.reference_pairs(plan, cfg, sample, admitted, block,
-                                    mix["sample_rows"])
+                                    mix["sample_rows"], devices=devs)
     _log(f"reference over {sample.size} rows")
     th = np.asarray(cfg["thetas"], np.float32)
     nums = reference.compare(got, ref, {g: th[plan.tenant[g]]
                                         for g in sample.tolist()})
+    shards = cfg.get("shards", 1)
+    if shards > 1:
+        # arrival i lies on shard i mod P: every micro-batch is full, or
+        # (the warm-up's) a multiple of P rows
+        cross = sum(1 for g, want in ref.items() for j, s in want.items()
+                    if s >= th[plan.tenant[g]] + reference.BAND
+                    and (g - j) % shards)
+        _log(f"{cross} of {nums['ref_pairs']} reference pairs join rows "
+             f"on different shards")
     nums["duplicate"] = duplicate
     nums["pairs_dropped"] = int(stats["pairs_dropped"])
     nums["window_overflow"] = int(stats["window_overflow"])

@@ -4,21 +4,25 @@ cells and metrics added as files, and faults that ``correct`` must catch."""
 import json
 import os
 import shutil
+from unittest import mock
 
 import numpy as np
 import pytest
 
 from bench import control, gen, run
+from repro.engine import sharded as sharded_engine
 from repro.runtime import MultiTenantRuntime
 from repro.serving.service import MultiTenantSSSJService
 
-from .tiny import cpu, tiny_cell
+from .tiny import cpu, on_devices, tiny_cell
 
 SEED = 2**31 + 5
 
 
-def test_no_tpu_exits_nonzero_without_a_result(capsys):
-    rc = run.main(["--workload", "dedup-d768.iso-sat", "--seed", "1",
+@pytest.mark.parametrize("workload", ["dedup-d768.iso-sat",
+                                      "dedup-d768x4.iso-sat"])
+def test_no_tpu_exits_nonzero_without_a_result(workload, capsys):
+    rc = run.main(["--workload", workload, "--seed", "1",
                    "--seconds", "1", "--trace", "0"])
     assert rc != 0
     assert capsys.readouterr().out == ""
@@ -85,14 +89,58 @@ def _alter(kind):
     return flush
 
 
-@pytest.mark.parametrize("workload", ["dedup-d768.iso-sat",
-                                      "trend-d384.burst-rate"])
-def test_sound_tiny_run_is_correct(workload):
+_real_merge = sharded_engine.merge_candidates
+
+
+def _shard0_only(cands, max_pairs):
+    """The level-3 merge given only shard 0's candidates: the others'
+    never reach it."""
+    keep = (np.arange(cands.kept.shape[0]) == 0).astype(np.int32)
+    return _real_merge(cands._replace(kept=cands.kept * keep,
+                                      emitted=cands.emitted * keep),
+                       max_pairs=max_pairs)
+
+
+def check_run(workload, fault=None):
+    """A tiny run of ``workload``: correct when sound; with the exchange
+    between chips left out, not correct, and by missing pairs alone."""
     cell = tiny_cell(run.load_cell, workload, anchored_share=0.2)
-    result, checks = run.run(cell, SEED, 1.0, False, chips=cpu)
-    assert result["correct"], checks
+    with mock.patch.object(sharded_engine, "merge_candidates",
+                           _shard0_only if fault else _real_merge):
+        result, checks = run.run(cell, SEED, 1.0, False, chips=cpu)
+    assert result["device"]["count"] == cell["workload"]["chips"]
     assert list(result)[-1] == "checks"
+    if fault:
+        assert not result["correct"]
+        assert {k for k, c in checks.items() if not c["ok"]} == \
+            {"missing"}, checks
+        return
+    assert result["correct"], checks
+    assert all(c["ok"] for c in checks.values()), checks
     assert checks["ref_pairs"]["value"] > 0
+
+
+@pytest.fixture(scope="module")
+def sharded():
+    """The runs on a mesh, made together on four devices."""
+    return on_devices(4, check_run, [("dedup-d768x4.iso-sat", None),
+                                     ("dedup-d768x4.iso-sat", "exchange")])
+
+
+@pytest.mark.parametrize("workload", ["dedup-d768.iso-sat",
+                                      "trend-d384.burst-rate",
+                                      "dedup-d768x4.iso-sat"])
+def test_sound_tiny_run_is_correct(workload, request):
+    if run.load_cell(workload)["workload"]["chips"] == 1:
+        check_run(workload)
+        return
+    failure = request.getfixturevalue("sharded")[(workload, None)]
+    assert failure is None, failure
+
+
+def test_exchange_left_out_is_not_correct(sharded):
+    failure = sharded[("dedup-d768x4.iso-sat", "exchange")]
+    assert failure is None, failure
 
 
 @pytest.mark.parametrize("kind", ["score", "drop"])
@@ -149,7 +197,8 @@ def test_service_faults_are_not_correct(fault, cls, patch, workload, fails,
 
 
 @pytest.mark.parametrize("workload", ["dedup-d768.iso-sat",
-                                      "trend-d384.burst-sat"])
+                                      "trend-d384.burst-sat",
+                                      "dedup-d768x4.iso-sat"])
 def test_bf16_control_is_not_correct(workload):
     cell = tiny_cell(run.load_cell, workload)
     out = control.control(cell, SEED, rows=256)

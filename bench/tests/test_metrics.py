@@ -1,6 +1,7 @@
 """The per-layer readers of the program's spans and latency histogram, on
-registry snapshots made up for the purpose."""
+registry snapshots and traces made up for the purpose."""
 
+import numpy as np
 import pytest
 
 from bench import run
@@ -83,3 +84,59 @@ def test_traced_tiny_run_reports_the_span_metrics(workload, present, absent):
     assert present <= set(got) and not absent & set(got), got
     for name in present:
         assert got[name]["value"] > 0.0 and got[name]["unit"] == "ms", name
+
+
+def _four_chip_trace():
+    """A traced window of 0..100 ns on four chips: on chip ``i`` the
+    kernel, an async all-gather in two halves, an all-reduce and two
+    other ops, each collective ``i`` ns longer than on chip 0."""
+    devices = {}
+    for i in range(4):
+        devices[f"/device:TPU:{i}"] = [
+            ["sssj_candidates.3", 0, 20], ["all-gather-start.1", 20, 2 + i],
+            ["all-gather-done.1", 30, 3 + i], ["all-reduce.7", 40, 5 + i],
+            ["reduce.4", 50, 6], ["fusion.2", 60, 10],
+        ]
+    return {"devices": devices, "host": [["bench.window", 0, 100]]}
+
+
+def test_collective_ms_reads_every_chip():
+    from bench import trace
+
+    r = _readings({"runtime/spans_dispatched": 0},
+                  {"runtime/spans_dispatched": 2})
+    r.trace = trace.reduce(_four_chip_trace())
+    # per chip 10 + 3i ns, mean 14.5 ns, over 2 spans of 4 micro-batches
+    assert _read("collective_ms", r) == pytest.approx(1e3 * 14.5e-9 / 8)
+    # one chip, no collective: nothing to read
+    one = _four_chip_trace()
+    one["devices"] = {"/device:TPU:0": [["sssj_candidates.3", 0, 20],
+                                        ["reduce.4", 50, 6]]}
+    r.trace = trace.reduce(one)
+    assert _read("collective_ms", r) is None
+
+
+@pytest.mark.parametrize("shards", [None, 4])
+def test_cand_roofline_takes_each_chips_share(shards):
+    from bench import gen, roofline
+
+    cfg = {"span": 4, "d": 768, "capacity": 4096, "horizon_share": 0.5,
+           "thetas": [0.85, 0.9, 0.95]}
+    if shards:
+        cfg["shards"] = shards
+    tenant = np.random.default_rng(0).integers(0, 3, 2048).astype(np.int32)
+    batches = [(a, a + 64) for a in range(1024, 2048, 64)]
+    r = Readings(cfg=cfg, device_kind="TPU v5 lite",
+                 trace={"op_s": {"sssj_candidates": 1e-3}, "busy_s": 1.0,
+                        "window_s": 1.0},
+                 before={}, after={}, batches=batches, tenant=tenant)
+    nbytes, flops = roofline.cand_work(tenant, gen.horizons(cfg), batches,
+                                       768)
+    t_min, _ = roofline.least_time(nbytes, flops,
+                                   roofline.peaks("TPU v5 lite"))
+    whole = 100.0 * float(t_min.sum()) / 1e-3
+    got = _read("cand_roofline", r)
+    if shards is None:
+        assert got == whole
+    else:
+        assert got == pytest.approx(whole / 4, rel=1e-12)

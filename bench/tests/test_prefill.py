@@ -6,9 +6,9 @@ import numpy as np
 import pytest
 
 from bench import client, gen, prefill
-from bench.run import load_cell
+from bench.run import load_cell, mesh_of
 
-from .tiny import TINY_CFG
+from .tiny import TINY_CFG, on_devices
 
 
 def _cell(name):
@@ -31,20 +31,47 @@ def _stream(svc, plan, lo, hi, block):
     ("dedup-d768.iso-sat", True),
     ("dedup-d768.iso-sat", False),
     ("trend-d384.burst-sat", True),
+    ("dedup-d768x4.iso-sat", True),
+    ("dedup-d768x4.iso-sat", False),
 ])
-def test_prefill_equals_streaming(name, gate):
+def test_prefill_equals_streaming(name, gate, request):
+    if load_cell(name)["workload"]["chips"] == 1:
+        check_prefill(name, gate)
+        return
+    failure = request.getfixturevalue("sharded")[(name, gate)]
+    assert failure is None, failure
+
+
+@pytest.fixture(scope="module")
+def sharded():
+    """The cases on a mesh, checked together on four devices."""
+    return on_devices(4, check_prefill, [("dedup-d768x4.iso-sat", True),
+                                         ("dedup-d768x4.iso-sat", False)])
+
+
+def check_prefill(name, gate):
+    import jax
+
     cfg, mix = _cell(name)
     cap = cfg["capacity"]
     span_rows = cfg["span"] * cfg["micro_batch"]
     n0 = cap - span_rows
     plan = gen.make_plan(cfg, mix, seed=2**31 + 11, n=cap + cap // 2)
+    mesh = mesh_of(cfg, jax.devices())
+    shards = cfg.get("shards", 1)
 
-    filled = prefill.build_service(cfg, gate=gate)
-    prefill.install(filled, plan, n0, block=256)
-    streamed = prefill.build_service(cfg, gate=gate)
+    filled = prefill.build_service(cfg, gate=gate, mesh=mesh)
+    # on a mesh too, each shard's ring is summarized in several parts
+    prefill.install(filled, plan, n0, block=256 // shards)
+    streamed = prefill.build_service(cfg, gate=gate, mesh=mesh)
     _stream(streamed, plan, 0, n0, span_rows)
 
     a, b = filled.runtime.state, streamed.runtime.state
+    # arrival i on shard i mod P, in that shard's own ring and cursor
+    assert np.asarray(a.cursor).shape == (() if shards == 1 else (shards,))
+    np.testing.assert_array_equal(
+        np.asarray(a.uids).reshape(shards, -1)[:, :n0 // shards],
+        np.arange(n0).reshape(-1, shards).T)
     np.testing.assert_allclose(np.asarray(a.vecs), np.asarray(b.vecs),
                                atol=1e-6)
     for field in ("ts", "uids", "sids", "cursor", "overflow",
