@@ -1,7 +1,9 @@
 """Client loops that drive ``MultiTenantSSSJService.submit``/``flush``.
 
 Both loops take the timed pool (rows, tenants, request starts) and use the
-public service API only; each request is one ``submit`` call.  A traffic
+public service API only; each request is one ``submit`` call, whose
+arguments the row representation's service side cuts from the pool's rows
+(``bench/serve/<name>.py``).  A traffic
 file picks one by ``client.kind``:
 
 * ``backlog`` — requests are submitted until ``spans_queued`` dispatch
@@ -26,6 +28,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import time
+from typing import Callable
 
 import numpy as np
 
@@ -37,18 +40,23 @@ __all__ = ["Pool", "WindowResult", "run_client", "poisson_due"]
 @dataclasses.dataclass
 class Pool:
     base: int              # arrival index of the pool's first row
-    vecs: np.ndarray       # (n, d) f32
+    rows: object           # the data side's host rows of the pool
     tenant: np.ndarray     # (n,) i32
     starts: np.ndarray     # (r,) first row of each request; starts[0] == 0
+    args: Callable         # (rows, a, b) -> submit's arguments for [a, b)
+
+    @property
+    def n(self) -> int:
+        return self.tenant.size
 
     @classmethod
-    def of(cls, plan, vecs: np.ndarray, lo: int) -> "Pool":
-        """The pool of arrivals ``[lo, lo + len(vecs))`` of ``plan``; a
-        request that began before ``lo`` keeps its rest as the first."""
-        hi = lo + vecs.shape[0]
+    def of(cls, plan, rows, lo: int, hi: int, args: Callable) -> "Pool":
+        """The pool of arrivals ``[lo, hi)`` of ``plan``, whose host rows
+        are ``rows``; a request that began before ``lo`` keeps its rest as
+        the first."""
         s = plan.starts[(plan.starts > lo) & (plan.starts < hi)] - lo
-        return cls(lo, vecs, plan.tenant[lo:hi],
-                   np.concatenate([[0], s]).astype(np.int64))
+        return cls(lo, rows, plan.tenant[lo:hi],
+                   np.concatenate([[0], s]).astype(np.int64), args)
 
 
 @dataclasses.dataclass
@@ -72,12 +80,11 @@ def _nothing(name):
 def _submit(svc, pool: Pool, r: int, refused, annotate) -> int:
     """Submit request ``r``; returns its row count (0 if refused)."""
     a = int(pool.starts[r])
-    b = int(pool.starts[r + 1]) if r + 1 < pool.starts.size else \
-        pool.vecs.shape[0]
+    b = int(pool.starts[r + 1]) if r + 1 < pool.starts.size else pool.n
     ts = np.arange(pool.base + a, pool.base + b, dtype=np.float64)
     try:
         with annotate("bench.submit"):
-            svc.submit(int(pool.tenant[a]), pool.vecs[a:b], ts)
+            svc.submit(int(pool.tenant[a]), *pool.args(pool.rows, a, b), ts)
     except TenantBackpressure:
         refused[a:b] = True
         return 0
@@ -103,7 +110,7 @@ def _backlog(svc, pool: Pool, cfg: dict, client: dict, seconds: float,
              annotate) -> WindowResult:
     mb = cfg["micro_batch"]
     chunk = client["spans_queued"] * cfg["span"] * mb
-    n = pool.vecs.shape[0]
+    n = pool.n
     refused = np.zeros(n, bool)
     flushes, flush_s = [], []
     r = queued = n_disp = 0
@@ -140,8 +147,7 @@ def _poisson(svc, pool: Pool, cfg: dict, client: dict, due_req: np.ndarray,
     mb = cfg["micro_batch"]
     wait = client["deadline_arrivals"] / client["rate"]
     n_req = due_req.size
-    n = int(pool.starts[n_req]) if n_req < pool.starts.size else \
-        pool.vecs.shape[0]
+    n = int(pool.starts[n_req]) if n_req < pool.starts.size else pool.n
     due = np.repeat(due_req, np.diff(np.append(pool.starts[:n_req], n)))
     refused = np.zeros(n, bool)
     t_done = np.full(n, np.nan)

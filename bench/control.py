@@ -9,7 +9,8 @@ query rows from the first ``--rows`` arrivals after the ring as a run
 does, and compares the pairs that the reference computes from bfloat16
 inputs against the float32 reference, by the numbers and limits that
 decide ``correct``.  It prints one JSON line per seed.  The benchmark's own
-runs never run it.
+runs never run it.  Rounding the inputs to bfloat16 is a notion of dense
+rows: a configuration of another row representation is refused.
 """
 
 from __future__ import annotations
@@ -29,12 +30,16 @@ import numpy as np  # noqa: E402
 
 def control(cell: dict, seed: int, rows: int) -> dict:
     from bench import gen, reference
-    from bench.run import _block_rows, _sample
+    from bench.rows.dense import block_rows
+    from bench.run import _sample
 
     cfg, mix = cell["cfg"], cell["mix"]
+    if cfg.get("rows", "dense") != "dense":
+        raise SystemExit(f"the bfloat16 control takes dense rows, not "
+                         f"{cfg['rows']!r}")
     cap = cfg["capacity"]
     plan = gen.make_plan(cfg, mix, seed, cap + rows)
-    block = _block_rows(cfg["d"], cap)
+    block = block_rows(cfg)
     window = np.arange(cap, cap + rows)
     sample = _sample(np.random.default_rng([seed, 2]), window,
                      plan.anchored[window], mix["sample_rows"])

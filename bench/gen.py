@@ -11,7 +11,9 @@ this module reads it, together with the cell's configuration, and makes
   the id of the vector it lies near.  ``"anchor": "row"`` plants near-
   duplicates (the anchor is an earlier row of the same tenant, the
   SemDeDup setting); ``"anchor": "story"`` plants bursty stories (the
-  anchor is a story centroid, the trend-detection setting);
+  anchor is a story centroid, the trend-detection setting).
+  :func:`schedule` makes this part, which every row representation's
+  plan shares (``bench/rows/``);
 * the **vectors**, on the device, from the plan and the seed: row ``i`` is
   ``unit(g_i)`` with ``g_i ~ N(0, I_d)`` drawn from ``fold_in(key, i)``,
   and an anchored row is ``unit(unit(a) + noise · unit(g_i))`` where ``a``
@@ -35,7 +37,7 @@ import numpy as np
 
 __all__ = [
     "Plan", "block_args", "horizons", "host_rows", "lam_of", "make_plan",
-    "request_starts", "rows", "rows_at", "tenant_weights",
+    "request_starts", "rows", "rows_at", "schedule", "tenant_weights",
 ]
 
 
@@ -168,7 +170,10 @@ def _story_anchors(rng, tenant, cfg, mix, weights):
     return anchor
 
 
-def make_plan(cfg: dict, mix: dict, seed: int, n: int) -> Plan:
+def schedule(cfg: dict, mix: dict, seed: int, n: int) -> dict:
+    """What every row representation's plan shares: ``n``, each arrival's
+    ``tenant``, the request ``starts``, each arrival's ``anchor`` and the
+    raw keys of the row and anchor draws."""
     rng, key_row, key_anchor = _keys(seed)
     weights = tenant_weights(len(cfg["thetas"]), mix["tenant_zipf"])
     starts = request_starts(mix, n)
@@ -181,9 +186,13 @@ def make_plan(cfg: dict, mix: dict, seed: int, n: int) -> Plan:
         anchor = _story_anchors(rng, tenant, cfg, mix, weights)
     else:
         raise ValueError(f"unknown anchor kind {mix['anchor']!r}")
-    return Plan(n=n, tenant=tenant.astype(np.int32), starts=starts,
-                anchor=anchor.astype(np.int64), key_row=key_row,
-                key_anchor=key_anchor, noise=float(mix["noise"]),
+    return {"n": n, "tenant": tenant.astype(np.int32), "starts": starts,
+            "anchor": anchor.astype(np.int64), "key_row": key_row,
+            "key_anchor": key_anchor}
+
+
+def make_plan(cfg: dict, mix: dict, seed: int, n: int) -> Plan:
+    return Plan(**schedule(cfg, mix, seed, n), noise=float(mix["noise"]),
                 d=int(cfg["d"]))
 
 

@@ -8,8 +8,8 @@ read (the harness then leaves the metric out of the result line).
 from __future__ import annotations
 
 import dataclasses
-import importlib.util
-import os
+
+from bench.load import module
 
 __all__ = ["Readings", "read_metric"]
 
@@ -44,9 +44,4 @@ class Readings:
 
 def read_metric(root: str, name: str, r: Readings):
     """Run the reader ``<root>/bench/metrics/<name>.py`` on ``r``."""
-    path = os.path.join(root, "bench", "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        f"bench.metrics.{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(r)
+    return module(root, "metrics", name).read(r)

@@ -6,13 +6,16 @@ mesh of them.
 
 ``<name>`` is a cell of ``BENCHMARK.json``: a configuration
 (``bench/configs/<config>.json``) under a traffic mix
-(``bench/traffic/<mix>.json``).  A run builds the service the configuration
-describes (its ring split over ``shards`` chips where the configuration
-has that key), fills its ring with the arrivals that precede the window
-(``bench/prefill.py``), warms up, drives ``submit``/``flush`` for
-``--seconds`` with the mix's client (``bench/client.py``), then checks a
-seeded sample of the window's rows against the plain reference
-(``bench/reference.py``).  With ``--trace 0`` it reports the cell's
+(``bench/traffic/<mix>.json``).  The configuration's ``rows`` names its row
+representation (absent: ``dense``), whose data side
+(``bench/rows/<rows>.py``) makes the stream and the plain reference and
+whose service side (``bench/serve/<rows>.py``) builds and fills the
+service.  A run builds the service the configuration describes (its ring
+split over ``shards`` chips where the configuration has that key), fills
+its ring with the arrivals that precede the window, warms up, drives
+``submit``/``flush`` for ``--seconds`` with the mix's client
+(``bench/client.py``), then checks a seeded sample of the window's rows
+against the plain reference.  With ``--trace 0`` it reports the cell's
 end-to-end metrics; with ``--trace 1`` it records a profiler trace of the
 window and reports the cell's per-layer metrics (``bench/metrics/``).
 
@@ -76,6 +79,16 @@ def load_cell(workload: str, root: str = ROOT) -> dict:
     }
 
 
+def row_sides(cell: dict):
+    """The data side and the service side of the configuration's row
+    representation (``rows``, absent: ``dense``), found by its name."""
+    from bench.load import module
+
+    name = cell["cfg"].get("rows", "dense")
+    return (module(cell["root"], "rows", name),
+            module(cell["root"], "serve", name))
+
+
 def enable_cache() -> str:
     """JAX's persistent compilation cache: the directory the environment
     names, else a fixed one inside the checkout."""
@@ -114,12 +127,6 @@ def mesh_of(cfg: dict, devs):
 def _log(msg: str) -> None:
     print(f"[{time.perf_counter() - T_START:8.3f} s] {msg}", file=sys.stderr,
           flush=True)
-
-
-def _block_rows(d: int, capacity: int) -> int:
-    """Rows made per device call: 256 MiB of f32 at most, and no more than
-    the ring holds."""
-    return min(1 << int(math.log2((1 << 26) // d)), capacity)
 
 
 def _sample(rng, rows: np.ndarray, anchored: np.ndarray, n: int):
@@ -203,10 +210,11 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     """One run of a cell; returns ``(result, checks)``."""
     import jax
 
-    from bench import client, gen, prefill, reference, trace as tr
+    from bench import client, gen, reference, trace as tr
     from bench.metrics import Readings, read_metric
 
     wl, cfg, mix = cell["workload"], cell["cfg"], cell["mix"]
+    data, serve = row_sides(cell)
     devs = chips(wl["chips"])[:wl["chips"]]
     dev = devs[0]
     _log(f"device {dev.device_kind} x{len(devs)}")
@@ -215,7 +223,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     # the warm-up makes the window's calls: a flush of two spans, one of
     # a micro-batch, and a padded one
     warm = (2 * span + 1) * mb + mb // 2
-    block = _block_rows(cfg["d"], cap)
+    block = data.block_rows(cfg)
     lo_i, hi_i = mix["request_items"]
     due_req = None
     if cl["kind"] == "poisson":
@@ -231,15 +239,18 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
         chunk = cl["spans_queued"] * span * mb
         n_pool = chunk * (math.ceil(mix["pool_items_per_s"] * seconds
                                     / chunk) + 1)
-    plan = gen.make_plan(cfg, mix, seed, cap + n_pool)
+    plan = data.make_plan(cfg, mix, seed, cap + n_pool)
     _log(f"plan of {cap} + {n_pool} arrivals")
-    svc = prefill.build_service(cfg, mesh=mesh_of(cfg, devs))
-    prefill.install(svc, plan, cap - warm, block)
+    svc = serve.build_service(cfg, mesh=mesh_of(cfg, devs))
+    serve.install(svc, plan, cap - warm, block)
     _log("ring filled")
-    warm_pool = client.Pool.of(
-        plan, gen.host_rows(plan, cap - warm, cap, block), cap - warm)
-    pool = client.Pool.of(plan, gen.host_rows(plan, cap, cap + n_pool,
-                                              block), cap)
+
+    def pool_of(lo, hi):
+        return client.Pool.of(plan, data.host_rows(plan, lo, hi, block), lo,
+                              hi, serve.submit_args)
+
+    warm_pool = pool_of(cap - warm, cap)
+    pool = pool_of(cap, cap + n_pool)
     _log("pool made")
     client.run_client(svc, warm_pool, cfg, {"kind": "backlog",
                                             "spans_queued": 2}, math.inf)
@@ -292,8 +303,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     gc.unfreeze()
     gc.collect()
 
-    ref = reference.reference_pairs(plan, cfg, sample, admitted, block,
-                                    mix["sample_rows"], devices=devs)
+    ref = data.reference_pairs(plan, cfg, sample, admitted, block,
+                               mix["sample_rows"], devices=devs)
     _log(f"reference over {sample.size} rows")
     th = np.asarray(cfg["thetas"], np.float32)
     nums = reference.compare(got, ref, {g: th[plan.tenant[g]]

@@ -37,25 +37,27 @@ def main(argv=None) -> int:
                     default=[0.5, 0.6, 0.7, 0.8, 0.9, 1.0, 1.1])
     args = ap.parse_args(argv)
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from bench import client, gen, prefill
-    from bench.run import _block_rows, chip, enable_cache, load_cell
+    from bench import client
+    from bench.run import chip, enable_cache, load_cell, row_sides
 
     cell = load_cell(args.workload)
     enable_cache()
     chip(cell["workload"]["chips"])
     cfg, mix = cell["cfg"], cell["mix"]
+    data, serve = row_sides(cell)
     cap, warm = cfg["capacity"], cfg["span"] * cfg["micro_batch"]
-    block = _block_rows(cfg["d"], cap)
+    block = data.block_rows(cfg)
     budget = int(8000 * args.seconds * (1 + len(args.fractions) * 1.2))
-    plan = gen.make_plan(cfg, mix, args.seed, cap + budget)
-    svc = prefill.build_service(cfg)
-    prefill.install(svc, plan, cap - warm, block)
+    plan = data.make_plan(cfg, mix, args.seed, cap + budget)
+    svc = serve.build_service(cfg)
+    serve.install(svc, plan, cap - warm, block)
     rng = np.random.default_rng([args.seed, 3])
     at = cap - warm
 
     def pool(n):
         nonlocal at
-        p = client.Pool.of(plan, gen.host_rows(plan, at, at + n, block), at)
+        p = client.Pool.of(plan, data.host_rows(plan, at, at + n, block),
+                           at, at + n, serve.submit_args)
         at += n
         return p
 

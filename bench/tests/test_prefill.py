@@ -7,6 +7,7 @@ import pytest
 
 from bench import client, gen, prefill
 from bench.run import load_cell, mesh_of
+from bench.serve.dense import submit_args
 
 from .tiny import TINY_CFG, on_devices
 
@@ -21,7 +22,8 @@ def _cell(name):
 
 def _stream(svc, plan, lo, hi, block):
     """Submit and flush arrivals [lo, hi) span by span; the flush results."""
-    pool = client.Pool.of(plan, gen.host_rows(plan, lo, hi, block), lo)
+    pool = client.Pool.of(plan, gen.host_rows(plan, lo, hi, block), lo, hi,
+                          submit_args)
     res = client.run_client(svc, pool, {"span": 1, "micro_batch": block},
                             {"kind": "backlog", "spans_queued": 1}, np.inf)
     return res.flushes + [svc.flush(final=True)]
